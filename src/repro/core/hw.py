@@ -170,3 +170,18 @@ def get_chip(name: str) -> ChipSpec:
     if name not in CHIPS:
         raise KeyError(f"unknown chip {name!r}; have {sorted(CHIPS)}")
     return CHIPS[name]
+
+
+# JAX's ``device_kind`` -> the chip whose published peaks apply.  A device
+# missing here has no peaks, and a caller that needs them must fail.
+DEVICE_KINDS: Mapping[str, ChipSpec] = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def chip_for_device_kind(kind: str) -> ChipSpec:
+    """Peaks for a device as ``jax.devices()[i].device_kind`` names it."""
+    if kind not in DEVICE_KINDS:
+        raise KeyError(
+            f"no peaks for device kind {kind!r}; have {sorted(DEVICE_KINDS)}")
+    return DEVICE_KINDS[kind]

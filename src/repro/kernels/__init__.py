@@ -9,7 +9,7 @@ auto-registered as a ``Workload`` (``kernel/<name>``) for
 
     from repro.kernels import registry
 
-    y = registry.GEMM(x, w)                  # interpret-mode Pallas
+    y = registry.GEMM(x, w)                  # compiled; interpret mode on CPU
     y = registry.GEMM.kernel(x, w)           # compiled Pallas path
     y_ref = registry.GEMM.ref(x, w)          # oracle
     registry.list_kernels()                  # all nine entry points

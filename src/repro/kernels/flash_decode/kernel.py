@@ -25,7 +25,7 @@ from jax.experimental import pallas as pl
 NEG_INF = -1e30
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, vl_ref, o_ref, m_ref, l_ref, acc_ref,
+def _decode_kernel(vl_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                    *, bs: int, ns: int):
     si = pl.program_id(2)
 
@@ -35,7 +35,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, vl_ref, o_ref, m_ref, l_ref, acc_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    valid = vl_ref[0]
+    valid = vl_ref[pl.program_id(0)]  # scalar-prefetched (SMEM)
     q = q_ref[0, 0]  # (G, D)
     k = k_ref[0, 0]  # (bs, D)
     v = v_ref[0, 0]
@@ -86,27 +86,30 @@ def flash_decode(
 
     kt = k.transpose(0, 2, 1, 3)  # (B, KV, S, D): head-major streaming
     vt = v.transpose(0, 2, 1, 3)
-    return pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,  # valid_len lives in SMEM
         grid=(B, KV, ns),
         in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, s: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, D), lambda b, h, s: (b, h, s, 0)),
-            pl.BlockSpec((1, 1, bs, D), lambda b, h, s: (b, h, s, 0)),
-            pl.BlockSpec((1,), lambda b, h, s: (b,)),
+            pl.BlockSpec((1, 1, G, D), lambda b, h, s, vl: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, bs, D), lambda b, h, s, vl: (b, h, s, 0)),
+            pl.BlockSpec((1, 1, bs, D), lambda b, h, s, vl: (b, h, s, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, s: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, s, vl: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G,), jnp.float32),
             pltpu.VMEM((G,), jnp.float32),
             pltpu.VMEM((G, D), jnp.float32),
         ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
         interpret=interpret,
-    )(q, kt, vt, valid_len)
+    )(valid_len, q, kt, vt)
 
 
-def _decode_kernel_paged(bt_ref, *refs, bs: int, ns: int,
+def _decode_kernel_paged(bt_ref, vl_ref, *refs, bs: int, ns: int,
                          quantized: bool = False):
     """Same online-softmax body as :func:`_decode_kernel`; the KV tile for
     logical block ``si`` of sequence ``b`` is DMA'd from pool block
@@ -118,10 +121,10 @@ def _decode_kernel_paged(bt_ref, *refs, bs: int, ns: int,
     the pool: int8 rows stream at 1/4 the HBM bytes and are widened back in
     VMEM right before the MXU contraction)."""
     if quantized:
-        (q_ref, k_ref, v_ref, ks_ref, vs_ref, vl_ref,
+        (q_ref, k_ref, v_ref, ks_ref, vs_ref,
          o_ref, m_ref, l_ref, acc_ref) = refs
     else:
-        q_ref, k_ref, v_ref, vl_ref, o_ref, m_ref, l_ref, acc_ref = refs
+        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
         ks_ref = vs_ref = None
     si = pl.program_id(2)
 
@@ -131,13 +134,13 @@ def _decode_kernel_paged(bt_ref, *refs, bs: int, ns: int,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    valid = vl_ref[0]
+    valid = vl_ref[pl.program_id(0)]
     q = q_ref[0, 0]  # (G, D)
     k = k_ref[0, 0]  # (bs, D) — one pool block
     v = v_ref[0, 0]
     if ks_ref is not None:  # dequantize the tile in VMEM, post-DMA
-        k = k.astype(jnp.float32) * ks_ref[0][:, None]
-        v = v.astype(jnp.float32) * vs_ref[0][:, None]
+        k = k.astype(jnp.float32) * ks_ref[0]  # (rows, 1) scale column
+        v = v.astype(jnp.float32) * vs_ref[0]
     elif k.dtype != q.dtype:  # bf16 pool: widen to the compute dtype
         k = k.astype(q.dtype)
         v = v.astype(q.dtype)
@@ -229,24 +232,24 @@ def flash_decode_paged(
     kt = k_pool.transpose(0, 2, 1, 3)  # (n_blocks, KV, bs, D): head-major
     vt = v_pool.transpose(0, 2, 1, 3)
     in_specs = [
-        pl.BlockSpec((1, 1, G, D), lambda b, h, s, bt: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, bs, D), lambda b, h, s, bt: (bt[b, s], h, 0, 0)),
-        pl.BlockSpec((1, 1, bs, D), lambda b, h, s, bt: (bt[b, s], h, 0, 0)),
+        pl.BlockSpec((1, 1, G, D), lambda b, h, s, bt, vl: (b, h, 0, 0)),
+        pl.BlockSpec((1, 1, bs, D), lambda b, h, s, bt, vl: (bt[b, s], h, 0, 0)),
+        pl.BlockSpec((1, 1, bs, D), lambda b, h, s, bt, vl: (bt[b, s], h, 0, 0)),
     ]
-    operands = [block_tables, q, kt, vt]
+    operands = [block_tables, valid_len, q, kt, vt]
     if quantized:
         in_specs += [
-            pl.BlockSpec((1, bs), lambda b, h, s, bt: (bt[b, s], 0)),
-            pl.BlockSpec((1, bs), lambda b, h, s, bt: (bt[b, s], 0)),
+            pl.BlockSpec((1, bs, 1), lambda b, h, s, bt, vl: (bt[b, s], 0, 0)),
+            pl.BlockSpec((1, bs, 1), lambda b, h, s, bt, vl: (bt[b, s], 0, 0)),
         ]
-        operands += [k_scale, v_scale]
-    in_specs.append(pl.BlockSpec((1,), lambda b, h, s, bt: (b,)))
-    operands.append(valid_len)
+        # (n_blocks, bs, 1) scale columns keep the blocks (8, 128)-tileable
+        operands += [k_scale[..., None], v_scale[..., None]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,  # block table and lengths live in SMEM
         grid=(B, KV, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, s, bt: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, G, D),
+                               lambda b, h, s, bt, vl: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G,), jnp.float32),
             pltpu.VMEM((G,), jnp.float32),
@@ -284,7 +287,6 @@ def flash_decode_paged_sharded(
     replicate, and the per-shard outputs concatenate on the head axis.
     No collective is needed: softmax normalizes within a head.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = int(mesh.shape[axis])
@@ -305,10 +307,10 @@ def flash_decode_paged_sharded(
             return flash_decode_paged(qi, kp, vp, bt, vl, k_scale=ks,
                                       v_scale=vs, interpret=interpret)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local, mesh=mesh,
             in_specs=(head_q, head_pool, head_pool, rep, rep1, rep, rep),
-            out_specs=head_q, check_rep=False,
+            out_specs=head_q, check_vma=False,
         )
         return fn(q, k_pool, v_pool, block_tables, valid_len,
                   k_scale, v_scale)
@@ -316,10 +318,10 @@ def flash_decode_paged_sharded(
     def local(qi, kp, vp, bt, vl):
         return flash_decode_paged(qi, kp, vp, bt, vl, interpret=interpret)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(head_q, head_pool, head_pool, rep, rep1),
-        out_specs=head_q, check_rep=False,
+        out_specs=head_q, check_vma=False,
     )
     return fn(q, k_pool, v_pool, block_tables, valid_len)
 
@@ -342,30 +344,55 @@ def _prefill_commit_kernel(bt_ref, qs_ref, ql_ref, kn_ref, vn_ref,
     once, so its content stays unspecified — exactly the idle-write
     contract the serving engine already relies on.
     """
-    si = pl.program_id(1)
-    q_start = qs_ref[0]
-    q_len = ql_ref[0]
-    pos = si * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)[0]
-    c_idx = pos - q_start
-    in_chunk = (c_idx >= 0) & (c_idx < q_len)  # valid_len predication
-    c_clip = jnp.clip(c_idx, 0, C - 1)
-    k_blk = kp_ref[0]  # (KV, bs, D)
-    v_blk = vp_ref[0]
-    k_over = jnp.take(kn_ref[0], c_clip, axis=1)  # (KV, bs, D) chunk rows
-    v_over = jnp.take(vn_ref[0], c_clip, axis=1)
-    sel = in_chunk[None, :, None]
-    ko_ref[0] = jnp.where(sel, k_over, k_blk)
-    vo_ref[0] = jnp.where(sel, v_over, v_blk)
+    in_chunk, k_over, v_over = _chunk_rows(qs_ref, ql_ref, kn_ref, vn_ref,
+                                           bs=bs, C=C)
+    sel = in_chunk[None]  # (1, bs, 1) valid_len predication
+    ko_ref[0] = jnp.where(sel, k_over.astype(ko_ref.dtype), kp_ref[0])
+    vo_ref[0] = jnp.where(sel, v_over.astype(vo_ref.dtype), vp_ref[0])
+
+
+def _chunk_rows(qs_ref, ql_ref, kn_ref, vn_ref, *, bs: int, C: int):
+    """The chunk rows that land in pool block ``si`` of slot ``b``.
+
+    Row ``r`` of the block holds global position ``si*bs + r``, i.e. chunk
+    row ``c = si*bs + r - q_start`` when ``0 <= c < q_len``.  Mosaic has no
+    1-D gather, so the rows are picked with a one-hot (bs, C) contraction
+    per KV head; each output row sums a single product by 1, so the pick
+    is exact.  Returns the (bs, 1) in-chunk predicate and the (KV, bs, D)
+    fp32 rows (zero outside the chunk).
+    """
+    b, si = pl.program_id(0), pl.program_id(1)
+    q_start = qs_ref[b]
+    q_len = ql_ref[b]
+    row = jax.lax.broadcasted_iota(jnp.int32, (bs, C), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (bs, C), 1)
+    pick = (si * bs + row - q_start == col) & (col < q_len)
+    c_idx = si * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0) - q_start
+    in_chunk = (c_idx >= 0) & (c_idx < q_len)
+
+    def rows(n_ref):
+        onehot = pick.astype(n_ref.dtype)
+        # fp32 rows need the full-precision MXU passes to stay exact
+        exact = (jax.lax.Precision.HIGHEST if n_ref.dtype == jnp.float32
+                 else None)
+        return jnp.stack([
+            jnp.dot(onehot, n_ref[0, h], preferred_element_type=jnp.float32,
+                    precision=exact)
+            for h in range(n_ref.shape[1])
+        ])
+
+    return in_chunk, rows(kn_ref), rows(vn_ref)
 
 
 def _quantize_rows_kernel(x):
     """Per-row symmetric int8: one fp32 scale per pool row, amax over the
     (heads, D) extent of that row.  ``x`` is (KV, bs, D); returns the int8
-    rows and the (bs,) scales."""
-    amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=(0, 2))
+    rows and the (bs, 1) scales."""
+    amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=0).max(
+        axis=-1, keepdims=True)
     s = jnp.maximum(amax / 127.0, 1e-8)
     q = jnp.clip(
-        jnp.round(x.astype(jnp.float32) / s[None, :, None]), -127, 127
+        jnp.round(x.astype(jnp.float32) / s[None]), -127, 127
     ).astype(jnp.int8)
     return q, s
 
@@ -378,26 +405,20 @@ def _prefill_commit_kernel_q(bt_ref, qs_ref, ql_ref, kn_ref, vn_ref,
     quantized to int8 with one fresh fp32 scale per pool row before the
     overlay, and the scale pools ride through the same block-table-indexed
     write-back (rows outside the chunk keep block AND scale bytes)."""
-    si = pl.program_id(1)
-    q_start = qs_ref[0]
-    q_len = ql_ref[0]
-    pos = si * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)[0]
-    c_idx = pos - q_start
-    in_chunk = (c_idx >= 0) & (c_idx < q_len)
-    c_clip = jnp.clip(c_idx, 0, C - 1)
-    k_over = jnp.take(kn_ref[0], c_clip, axis=1)  # (KV, bs, D) chunk rows
-    v_over = jnp.take(vn_ref[0], c_clip, axis=1)
+    in_chunk, k_over, v_over = _chunk_rows(qs_ref, ql_ref, kn_ref, vn_ref,
+                                           bs=bs, C=C)
     kq, ks = _quantize_rows_kernel(k_over)
     vq, vs = _quantize_rows_kernel(v_over)
-    sel = in_chunk[None, :, None]
+    sel = in_chunk[None]
     ko_ref[0] = jnp.where(sel, kq, kp_ref[0])
     vo_ref[0] = jnp.where(sel, vq, vp_ref[0])
     kso_ref[0] = jnp.where(in_chunk, ks, ksp_ref[0])
     vso_ref[0] = jnp.where(in_chunk, vs, vsp_ref[0])
 
 
-def _prefill_attn_kernel(bt_ref, *refs, block_c: int, block_s: int,
-                         ns: int, G: int, quantized: bool = False):
+def _prefill_attn_kernel(bt_ref, qs_ref, ql_ref, *refs, block_c: int,
+                         block_s: int, ns: int, G: int,
+                         quantized: bool = False):
     """Causal online-softmax over one (query-tile, KV-block) grid cell.
 
     Same running (max, sum, acc) recurrence as :func:`_decode_kernel_paged`
@@ -410,11 +431,9 @@ def _prefill_attn_kernel(bt_ref, *refs, block_c: int, block_s: int,
     scales, exactly as the decode kernel does.
     """
     if quantized:
-        (q_ref, k_ref, v_ref, ks_ref, vs_ref, qs_ref, ql_ref,
-         o_ref, m_ref, l_ref, acc_ref) = refs
+        q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = refs
     else:
-        (q_ref, k_ref, v_ref, qs_ref, ql_ref,
-         o_ref, m_ref, l_ref, acc_ref) = refs
+        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
         ks_ref = vs_ref = None
     qi = pl.program_id(2)
     si = pl.program_id(3)
@@ -425,31 +444,29 @@ def _prefill_attn_kernel(bt_ref, *refs, block_c: int, block_s: int,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q_start = qs_ref[0]
-    q = q_ref[0, 0]  # (block_c, G, D)
+    q_start = qs_ref[pl.program_id(0)]
+    q = q_ref[0, 0]  # (block_c * G, D): row r is chunk row r // G
     D = q.shape[-1]
-    q = q.reshape(block_c * G, D)
     k = k_ref[0, 0]  # (block_s, D)
     v = v_ref[0, 0]
     if ks_ref is not None:
-        k = k.astype(jnp.float32) * ks_ref[0][:, None]
-        v = v.astype(jnp.float32) * vs_ref[0][:, None]
+        k = k.astype(jnp.float32) * ks_ref[0]  # (rows, 1) scale column
+        v = v.astype(jnp.float32) * vs_ref[0]
     elif k.dtype != q.dtype:
         k = k.astype(q.dtype)
         v = v.astype(q.dtype)
     scale = 1.0 / math.sqrt(D)
 
-    pos = si * block_s + jax.lax.broadcasted_iota(jnp.int32, (1, block_s), 1)[0]
-    q_idx = jax.lax.broadcasted_iota(jnp.int32, (block_c, G), 0).reshape(
-        block_c * G
-    ) + qi * block_c
+    pos = si * block_s + jax.lax.broadcasted_iota(jnp.int32, (1, block_s), 1)
+    q_idx = (jax.lax.broadcasted_iota(jnp.int32, (block_c * G, 1), 0) // G
+             + qi * block_c)
     limit = q_start + q_idx  # last key position each query row may see
 
     # skip KV blocks entirely beyond this query tile's causal frontier
     @pl.when(si * block_s <= q_start + (qi + 1) * block_c - 1)
     def _work():
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        s = jnp.where(pos[None, :] <= limit[:, None], s, NEG_INF)
+        s = jnp.where(pos <= limit, s, NEG_INF)
         m_new = jnp.maximum(m_ref[...], s.max(axis=-1))
         p = jnp.exp(s - m_new[:, None])
         corr = jnp.exp(m_ref[...] - m_new)
@@ -462,9 +479,7 @@ def _prefill_attn_kernel(bt_ref, *refs, block_c: int, block_s: int,
     @pl.when(si == ns - 1)
     def _flush():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (
-            (acc_ref[...] / l[:, None]).reshape(block_c, G, D).astype(o_ref.dtype)
-        )
+        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
 def flash_prefill_paged(
@@ -533,13 +548,14 @@ def flash_prefill_paged(
     kn = k_new.transpose(0, 2, 1, 3)   # (B, KV, C, D)
     vn = v_new.transpose(0, 2, 1, 3)
 
-    pool_spec = pl.BlockSpec((1, KV, bs, D), lambda b, s, bt: (bt[b, s], 0, 0, 0))
-    scale_spec = pl.BlockSpec((1, bs), lambda b, s, bt: (bt[b, s], 0))
+    # block table, q_start and q_len are scalar-prefetched into SMEM
+    pool_spec = pl.BlockSpec((1, KV, bs, D),
+                             lambda b, s, bt, qs, ql: (bt[b, s], 0, 0, 0))
+    scale_spec = pl.BlockSpec((1, bs, 1),
+                              lambda b, s, bt, qs, ql: (bt[b, s], 0, 0))
     commit_in = [
-        pl.BlockSpec((1,), lambda b, s, bt: (b,)),
-        pl.BlockSpec((1,), lambda b, s, bt: (b,)),
-        pl.BlockSpec((1, KV, C, D), lambda b, s, bt: (b, 0, 0, 0)),
-        pl.BlockSpec((1, KV, C, D), lambda b, s, bt: (b, 0, 0, 0)),
+        pl.BlockSpec((1, KV, C, D), lambda b, s, bt, qs, ql: (b, 0, 0, 0)),
+        pl.BlockSpec((1, KV, C, D), lambda b, s, bt, qs, ql: (b, 0, 0, 0)),
         pool_spec, pool_spec,
     ]
     commit_out = [pool_spec, pool_spec]
@@ -549,11 +565,13 @@ def flash_prefill_paged(
         jax.ShapeDtypeStruct(vp.shape, vp.dtype),
     ]
     # pool (and scale) operands alias their outputs so unvisited blocks
-    # keep their bytes (indices count the scalar-prefetch operand)
+    # keep their bytes (indices count the scalar-prefetch operands)
     aliases = {5: 0, 6: 1}
     if quantized:
         commit_in += [scale_spec, scale_spec]
         commit_out += [scale_spec, scale_spec]
+        # scales travel as (n_blocks, bs, 1) columns: (8, 128)-tileable
+        k_scale, v_scale = k_scale[..., None], v_scale[..., None]
         commit_operands += [k_scale, v_scale]
         commit_shapes += [
             jax.ShapeDtypeStruct(k_scale.shape, k_scale.dtype),
@@ -561,7 +579,7 @@ def flash_prefill_paged(
         ]
         aliases = {5: 0, 6: 1, 7: 2, 8: 3}
     commit_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=3,
         grid=(B, nb),
         in_specs=commit_in,
         out_specs=commit_out,
@@ -582,35 +600,32 @@ def flash_prefill_paged(
     else:
         kp, vp = committed
 
-    qh = q.transpose(0, 2, 1, 3, 4)  # (B, KV, C, G, D)
+    # (B, KV, C * G, D): the G grouped heads of a chunk row are adjacent
+    qh = q.transpose(0, 2, 1, 3, 4).reshape(B, KV, C * G, D)
+
+    def kv_tile(b, h, qi, s, bt, qs, ql):
+        return (bt[b, s // spp], h, s % spp, 0)
+
+    def scale_tile(b, h, qi, s, bt, qs, ql):
+        return (bt[b, s // spp], s % spp, 0)
+
+    def q_tile(b, h, qi, s, bt, qs, ql):
+        return (b, h, qi, 0)
+
     attn_in = [
-        pl.BlockSpec((1, 1, bc, G, D),
-                     lambda b, h, qi, s, bt: (b, h, qi, 0, 0)),
-        pl.BlockSpec((1, 1, bks, D),
-                     lambda b, h, qi, s, bt: (bt[b, s // spp], h, s % spp, 0)),
-        pl.BlockSpec((1, 1, bks, D),
-                     lambda b, h, qi, s, bt: (bt[b, s // spp], h, s % spp, 0)),
+        pl.BlockSpec((1, 1, bc * G, D), q_tile),
+        pl.BlockSpec((1, 1, bks, D), kv_tile),
+        pl.BlockSpec((1, 1, bks, D), kv_tile),
     ]
-    attn_operands = [block_tables, qh, kp, vp]
+    attn_operands = [block_tables, q_start, q_len, qh, kp, vp]
     if quantized:
-        attn_in += [
-            pl.BlockSpec((1, bks),
-                         lambda b, h, qi, s, bt: (bt[b, s // spp], s % spp)),
-            pl.BlockSpec((1, bks),
-                         lambda b, h, qi, s, bt: (bt[b, s // spp], s % spp)),
-        ]
+        attn_in += [pl.BlockSpec((1, bks, 1), scale_tile)] * 2
         attn_operands += [k_scale, v_scale]
-    attn_in += [
-        pl.BlockSpec((1,), lambda b, h, qi, s, bt: (b,)),
-        pl.BlockSpec((1,), lambda b, h, qi, s, bt: (b,)),
-    ]
-    attn_operands += [q_start, q_len]
     attn_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=3,
         grid=(B, KV, C // bc, ns),
         in_specs=attn_in,
-        out_specs=pl.BlockSpec((1, 1, bc, G, D),
-                               lambda b, h, qi, s, bt: (b, h, qi, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, bc * G, D), q_tile),
         scratch_shapes=[
             pltpu.VMEM((bc * G,), jnp.float32),
             pltpu.VMEM((bc * G,), jnp.float32),
@@ -621,13 +636,13 @@ def flash_prefill_paged(
         functools.partial(_prefill_attn_kernel, block_c=bc, block_s=bks,
                           ns=ns, G=G, quantized=quantized),
         grid_spec=attn_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, C, G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KV, C * G, D), q.dtype),
         interpret=interpret,
     )(*attn_operands)
 
-    out = out.transpose(0, 2, 1, 3, 4)
+    out = out.reshape(B, KV, C, G, D).transpose(0, 2, 1, 3, 4)
     kp = kp.transpose(0, 2, 1, 3)
     vp = vp.transpose(0, 2, 1, 3)
     if quantized:
-        return out, kp, vp, k_scale, v_scale
+        return out, kp, vp, k_scale[..., 0], v_scale[..., 0]
     return out, kp, vp

@@ -18,10 +18,14 @@ the socket's bandwidth saturates at ~8 threads).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+
+_TILE_AMPS = 1 << 16
 
 
 def _rx_kernel(re_ref, im_ref, ore_ref, oim_ref, *, cos: float, sin: float):
@@ -44,10 +48,15 @@ def rx_gate(
     qubit: int,
     theta: float,
     *,
-    block_outer: int = 256,
+    block_outer: Optional[int] = None,
     interpret: bool = True,
 ):
-    """Apply RX(theta) on ``qubit`` to the state (re, im), both (2**n,)."""
+    """Apply RX(theta) on ``qubit`` to the state (re, im), both (2**n,).
+
+    ``block_outer`` defaults to tiles of about ``_TILE_AMPS`` amplitudes
+    per plane, counting the inner axis at no less than one 128-lane row:
+    256 outer rows up to qubit 6, fewer above, so the double-buffered
+    in/out tiles stay inside the default scoped VMEM at any qubit."""
     import math
 
     n_amp = re.shape[0]
@@ -57,7 +66,7 @@ def rx_gate(
     c, s = math.cos(theta / 2), math.sin(theta / 2)
     re3 = re.reshape(outer, 2, inner)
     im3 = im.reshape(outer, 2, inner)
-    bo = min(block_outer, outer)
+    bo = min(block_outer or max(1, _TILE_AMPS // (2 * max(inner, 128))), outer)
     assert outer % bo == 0
     kernel = functools.partial(_rx_kernel, cos=c, sin=s)
     ore, oim = pl.pallas_call(

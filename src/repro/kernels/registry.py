@@ -7,7 +7,8 @@ Each ``kernels/<pkg>/ops.py`` used to hand-roll the same
 :func:`register_kernel` replaces those six copies with one factory that
 returns a :class:`KernelOps` exposing the call surfaces:
 
-* ``op(*args)``        — default call (interpret-mode Pallas, CPU-safe);
+* ``op(*args)``        — default call: the compiled kernel, or interpret
+  mode on the CPU backend, where it is the only option;
 * ``op.kernel(*args)`` — compiled Pallas path (``interpret=False``);
 * ``op.interpret(*args)`` — explicit interpret-mode path;
 * ``op.ref(*args)``    — the pure-jnp/numpy oracle.
@@ -32,6 +33,16 @@ import jax
 from repro.analysis.workload import Workload, register_lazy
 from repro.tuning import spaces as _spaces
 from repro.tuning.space import TuningSpace, canonical_dtype
+
+
+def default_interpret() -> bool:
+    """Whether a call that does not choose runs the Pallas interpreter.
+
+    Only the CPU backend defaults to it, because it cannot run a compiled
+    TPU kernel; on a TPU backend the default is the compiled kernel, so a
+    run on the chip never measures the interpreter by accident.
+    """
+    return jax.default_backend() == "cpu"
 
 
 class KernelOps:
@@ -150,10 +161,7 @@ class KernelOps:
                 k: v for k, v in kw.items()
                 if k != "interpret" and k not in space.axes
             }
-            try:
-                valid = space.validate(view, args, extra=extra)
-            except Exception:
-                valid = None
+            valid = space.validate(view, args, extra=extra)
             if valid is None:  # the call's config does not fit: fall back
                 return kw
             cfg = valid
@@ -164,7 +172,7 @@ class KernelOps:
     # -- call surfaces -------------------------------------------------------
 
     def __call__(self, *args: Any, **kw: Any):
-        kw.setdefault("interpret", True)
+        kw.setdefault("interpret", default_interpret())
         kw = self._tuned_kwargs(args, kw)
         return self._jit(*args, **kw)
 
@@ -179,7 +187,8 @@ class KernelOps:
         return self._jit(*args, **kw)
 
     def lower(self, *args: Any, **kw: Any):
-        """AOT-lower the (interpret-mode by default) jitted kernel.
+        """AOT-lower the jitted kernel (interpret mode only by default on
+        the CPU backend, as for ``op(...)``).
 
         Exposing ``lower`` lets the analysis pipeline compile a kernel
         workload directly instead of re-wrapping it in ``jax.jit`` — which
@@ -187,7 +196,7 @@ class KernelOps:
         config is resolved here too (``fingerprint_extra`` keeps the
         artifact store's content addresses distinct per config).
         """
-        kw.setdefault("interpret", True)
+        kw.setdefault("interpret", default_interpret())
         kw = self._tuned_kwargs(args, kw)
         return self._jit.lower(*args, **kw)
 

@@ -9,8 +9,12 @@ the paper's synthetic repeat-K loop (Sec. 3.2) as a ``fori_loop`` with a
 loop-carried accumulator (their `#pragma unroll(1)` + no-DCE trick — the
 carried dependency stops XLA from folding the K FMAs).
 
-Grid: one program per row-block.  VMEM per step: the (8, width) value/index
-tiles + the dense x (gathered); x stays resident across programs.
+The x gather runs in the wrapper, as an XLA gather: Mosaic has no 1-D
+gather from VMEM, so the kernel receives the (nb, rb, width) gathered
+operand beside the values and keeps the lane predicate and the FMA loop.
+
+Grid: one program per row-block.  Row lengths and results travel as
+(nb, rb, 1) columns so every block is (8, 128)-tileable.
 """
 
 from __future__ import annotations
@@ -22,23 +26,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _spmv_kernel(values_ref, col_ref, nnz_ref, x_ref, y_ref, *, repeat: int):
+def _spmv_kernel(values_ref, xg_ref, nnz_ref, y_ref, *, repeat: int):
     vals = values_ref[0]  # (rb, width)
-    cols = col_ref[0]
-    nnz = nnz_ref[0]  # (rb,)
     rb, width = vals.shape
     lane = jax.lax.broadcasted_iota(jnp.int32, (rb, width), 1)
-    pred = lane < nnz[:, None]  # predicate register analogue
-    x = x_ref[...]
-    gathered = jnp.take(x, cols, axis=0)  # (rb, width) gather from VMEM
-    contrib = jnp.where(pred, vals * gathered, 0.0)
+    pred = lane < nnz_ref[0]  # (rb, 1) lengths: predicate register analogue
+    contrib = jnp.where(pred, vals * xg_ref[0], 0.0)
     inv = jnp.asarray(1.0 / repeat, vals.dtype)
 
     def body(_, acc):
         # loop-carried FMA: repeat x the arithmetic intensity, same result
-        return acc + contrib.sum(axis=-1) * inv
+        return acc + contrib.sum(axis=-1, keepdims=True) * inv
 
-    acc0 = jnp.zeros((rb,), vals.dtype)
+    acc0 = jnp.zeros((rb, 1), vals.dtype)
     y_ref[0] = jax.lax.fori_loop(0, repeat, body, acc0)
 
 
@@ -47,7 +47,6 @@ def spmv_blockell(values, col_idx, row_nnz, x, *, repeat: int = 1,
     """y = A @ x for block-ELL A.  values/col_idx: (nb, rb, width);
     row_nnz: (nb, rb); x: (n_cols,).  Returns (nb*rb,)."""
     nb, rb, width = values.shape
-    n_cols = x.shape[0]
     kernel = functools.partial(_spmv_kernel, repeat=repeat)
     y = pl.pallas_call(
         kernel,
@@ -55,13 +54,12 @@ def spmv_blockell(values, col_idx, row_nnz, x, *, repeat: int = 1,
         in_specs=[
             pl.BlockSpec((1, rb, width), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, rb, width), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, rb), lambda i: (i, 0)),
-            pl.BlockSpec((n_cols,), lambda i: (0,)),  # x resident in VMEM
+            pl.BlockSpec((1, rb, 1), lambda i: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, rb), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, rb), values.dtype),
+        out_specs=pl.BlockSpec((1, rb, 1), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb, rb, 1), values.dtype),
         interpret=interpret,
-    )(values, col_idx, row_nnz, x)
+    )(values, x[col_idx], row_nnz[..., None])
     return y.reshape(nb * rb)
 
 
@@ -71,13 +69,9 @@ def spmv_fixed_width(values, col_idx, row_nnz, x, *, interpret: bool = True):
     case).  Numerically identical (padding values are zero); the cost model
     differs (see kernels.spmv.ops.issue_counts)."""
     nb, rb, width = values.shape
-    n_cols = x.shape[0]
 
-    def kernel(values_ref, col_ref, x_ref, y_ref):
-        vals = values_ref[0]
-        cols = col_ref[0]
-        x_ = x_ref[...]
-        y_ref[0] = (vals * jnp.take(x_, cols, axis=0)).sum(axis=-1)
+    def kernel(values_ref, xg_ref, y_ref):
+        y_ref[0] = (values_ref[0] * xg_ref[0]).sum(axis=-1, keepdims=True)
 
     y = pl.pallas_call(
         kernel,
@@ -85,10 +79,9 @@ def spmv_fixed_width(values, col_idx, row_nnz, x, *, interpret: bool = True):
         in_specs=[
             pl.BlockSpec((1, rb, width), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, rb, width), lambda i: (i, 0, 0)),
-            pl.BlockSpec((n_cols,), lambda i: (0,)),
         ],
-        out_specs=pl.BlockSpec((1, rb), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, rb), values.dtype),
+        out_specs=pl.BlockSpec((1, rb, 1), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb, rb, 1), values.dtype),
         interpret=interpret,
-    )(values, col_idx, x)
+    )(values, x[col_idx])
     return y.reshape(nb * rb)

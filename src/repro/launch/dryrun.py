@@ -1,10 +1,11 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"  # host devices only: never claim a chip
 
 """Multi-pod dry-run: lower + compile every (architecture x input-shape x
 mesh) cell and record cost/memory/collective analysis for §Roofline.
 
-MUST be run as its own process (the two lines above must execute before any
+MUST be run as its own process (the three lines above must execute before any
 jax import anywhere — including ``from repro...``).  Smoke tests and benches
 never import this module, so they see 1 device.
 

@@ -31,7 +31,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
+
+
+def _auto(n: int) -> tuple:
+    """Auto axis types: shardings propagate through the partitioner, as the
+    code here is written for (``jax.make_mesh`` defaults to Explicit)."""
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 def make_host_mesh(model_axis: int = 1):
@@ -44,7 +50,8 @@ def make_host_mesh(model_axis: int = 1):
             shape=(n, model_axis),
             n_devices=n,
         )
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"),
+                         axis_types=_auto(2))
 
 
 def parse_mesh(spec: str) -> Tuple[int, int]:

@@ -129,4 +129,8 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    # the command line only: tests that call main() leave the cache off
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     raise SystemExit(main())

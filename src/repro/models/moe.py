@@ -175,7 +175,6 @@ def _moe_ffn_shard_map(params, cfg, x, plan) -> Tuple[jax.Array, jax.Array]:
     GSPMD emits for the global layout (measured 98 TB -> ~8 TB per step on
     deepseek-moe-16b@train_4k).
     """
-    from jax.experimental.shard_map import shard_map
 
     m = cfg.moe
     B, S, d = x.shape
@@ -221,11 +220,11 @@ def _moe_ffn_shard_map(params, cfg, x, plan) -> Tuple[jax.Array, jax.Array]:
         P(None, None),         # router replicated
         P(dp, None, None),     # x: batch over data axes
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         per_device, mesh=plan.mesh,
         in_specs=specs_in,
         out_specs=(P(dp, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     y, aux = fn(params["wi_gate"], params["wi_up"], params["wo"],
                 params["router"], x)

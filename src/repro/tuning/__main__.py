@@ -35,9 +35,10 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap.add_argument("--dtypes", nargs="+", default=None,
                     help="ELEN axis: explicit dtypes, or 'space' to sweep "
                          "each kernel space's own candidates")
-    ap.add_argument("--mode", default="interpret",
+    ap.add_argument("--mode", default=None,
                     choices=["interpret", "compiled"],
-                    help="timing mode for survivors")
+                    help="timing mode for survivors (default: compiled on "
+                         "an accelerator, interpret on the CPU backend)")
     ap.add_argument("--keep", type=int, default=4,
                     help="survivors timed after roofline pruning")
     ap.add_argument("--cap", type=int, default=None,

@@ -18,6 +18,7 @@ not kernel call arguments.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, Tuple
 
 from repro.tuning.space import TuningSpace
@@ -166,7 +167,7 @@ def stream_space(n_arrays: int, flops_per_elem: float) -> TuningSpace:
 
 
 # ---------------------------------------------------------------------------
-# Jacobi2D — input resident per program: block_rows trades re-reads
+# Jacobi2D — row tiles plus one aligned halo block above and below
 # ---------------------------------------------------------------------------
 
 
@@ -181,19 +182,26 @@ def _jacobi_ok(cfg: Dict[str, Any], args: Tuple) -> bool:
     return H % br == 0
 
 
+def _jacobi_halo(br: int, dtype_bytes: int) -> int:
+    return math.gcd(br, 32 // dtype_bytes)
+
+
 def _jacobi_vmem(cfg: Dict[str, Any], args: Tuple, dtype_bytes: int) -> float:
     H, W = args[0].shape
     br = min(cfg["block_rows"], H)
-    return float(H * W * dtype_bytes + br * W * dtype_bytes)  # resident + tile
+    hb = _jacobi_halo(br, dtype_bytes)
+    # double-buffered input tile + two halo blocks + output tile
+    return float(2 * (2 * br + 2 * hb) * W * dtype_bytes)
 
 
 def _jacobi_traffic(cfg: Dict[str, Any], args: Tuple) -> float:
-    """The resident input is re-fetched by every grid step (no inter-program
-    reuse guarantee), so larger row blocks mean fewer sweeps over u."""
+    """Each row block re-reads two halo blocks, so larger row blocks mean
+    less halo traffic on top of one read and one write of u."""
     H, W = args[0].shape
     b = args[0].dtype.itemsize
     br = min(cfg["block_rows"], H)
-    return float(H * W * b * (H // br) + H * W * b)
+    hb = _jacobi_halo(br, b)
+    return float(H * W * b * (1 + 2 * hb / br) + H * W * b)
 
 
 def jacobi2d_space() -> TuningSpace:

@@ -107,6 +107,14 @@ def _cast_args(args: Tuple, dtype: str) -> Tuple:
     return tuple(out)
 
 
+def default_mode() -> str:
+    """Timing mode when none is given: compiled kernels on an accelerator,
+    the interpreter on the CPU backend (the only mode it can run)."""
+    from repro.kernels.registry import default_interpret
+
+    return "interpret" if default_interpret() else "compiled"
+
+
 def _time_config(
     ops: Any,
     args: Tuple,
@@ -183,7 +191,7 @@ def tune(
     dtype: Optional[str] = None,
     space: Optional[TuningSpace] = None,
     store: Any = "default",
-    mode: str = "interpret",
+    mode: Optional[str] = None,
     keep: int = 4,
     repeats: int = 2,
     min_time_s: float = 0.0,
@@ -204,6 +212,7 @@ def tune(
       calls resolve it automatically (explicit kwargs still win).
     """
     ops = _resolve_ops(kernel)
+    mode = mode or default_mode()
     space = space or getattr(ops, "tuning_space", None)
     if space is None:
         raise ValueError(f"kernel {ops.name!r} has no TuningSpace")
@@ -400,7 +409,7 @@ def tune_kernels(
     jobs: int = 1,
     cap: Optional[int] = None,
     store: Any = "default",
-    mode: str = "interpret",
+    mode: Optional[str] = None,
     keep: int = 4,
     repeats: int = 2,
     min_time_s: float = 0.0,

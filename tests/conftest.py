@@ -12,6 +12,8 @@ import os
 import tempfile
 
 os.environ["REPRO_ARTIFACT_DIR"] = tempfile.mkdtemp(prefix="repro-artifacts-")
+# the suite runs on the CPU (interpret-mode kernels) and never holds a chip
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
 import pytest

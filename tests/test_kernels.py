@@ -428,3 +428,82 @@ def test_rx_two_pi_is_minus_identity():
     o_re, o_im = qk.rx_gate(re, im, 3, 2 * math.pi, block_outer=2)
     np.testing.assert_allclose(np.asarray(o_re), -np.asarray(re), atol=1e-5)
     np.testing.assert_allclose(np.asarray(o_im), -np.asarray(im), atol=1e-5)
+
+
+def test_rx_default_tile_follows_the_inner_axis():
+    """The default outer tile shrinks as the target qubit widens the inner
+    axis, so the in/out tiles fit VMEM at any qubit; results are unchanged."""
+    re = jax.random.normal(jax.random.PRNGKey(8), (1 << 12,), jnp.float32)
+    im = jax.random.normal(jax.random.PRNGKey(9), (1 << 12,), jnp.float32)
+    for qubit in (0, 9, 11):
+        o_re, o_im = qk.rx_gate(re, im, qubit, 0.7)
+        r_re, r_im = qr.rx_ref(re, im, qubit, 0.7)
+        np.testing.assert_allclose(np.asarray(o_re), r_re, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(o_im), r_im, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# Registry call surface: default mode and tuned-config validation
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("name", ["gemm", "stream-triad", "jacobi2d"])
+def test_default_call_and_lower_interpret_on_cpu(name):
+    """On the CPU backend the interpreter is the only option, so ``op(...)``
+    and ``op.lower(...)`` default to it: the result is the interpret-mode
+    one and the lowered program holds no TPU kernel."""
+    from repro.kernels import registry
+    from repro.tuning.tune import default_mode
+
+    assert jax.default_backend() == "cpu"
+    assert registry.default_interpret() and default_mode() == "interpret"
+    ops = registry.get_kernel(name)
+    x = jax.random.normal(jax.random.PRNGKey(0), (64, 128), jnp.float32)
+    args = {"gemm": (x, x.T), "stream-triad": (x, x, 2.0),
+            "jacobi2d": (x,)}[name]
+    np.testing.assert_array_equal(np.asarray(ops(*args)),
+                                  np.asarray(ops.interpret(*args)))
+    assert "tpu_custom_call" not in ops.lower(*args).as_text()
+
+
+class _RaisingSpace:
+    """A tuning space whose validation itself is broken."""
+
+    axes = {"bm": (64,), "bn": (64,), "bk": (64,)}
+
+    def validate(self, config, args, *, extra=None):
+        raise RuntimeError("validation bug")
+
+
+def test_tuned_config_validation_error_propagates(monkeypatch):
+    from repro.kernels import registry
+
+    ops = registry.get_kernel("gemm")
+    x = jnp.ones((128, 128), jnp.float32)
+    try:
+        ops.set_tuned({"bm": 64, "bn": 64, "bk": 64}, chip="c", dtype="fp32")
+        monkeypatch.setattr(ops, "tuning_space", _RaisingSpace())
+        with pytest.raises(RuntimeError, match="validation bug"):
+            ops._tuned_kwargs((x, x), {"interpret": True})
+        with pytest.raises(RuntimeError, match="validation bug"):
+            ops(x, x)
+    finally:
+        ops.clear_tuned()
+
+
+def test_tuned_config_that_does_not_fit_falls_back_to_defaults():
+    from repro.kernels import registry
+
+    ops = registry.get_kernel("gemm")
+    x = jax.random.normal(jax.random.PRNGKey(1), (256, 256), jnp.float32)
+    try:
+        # 256 is not a multiple of 192: the config does not fit this call
+        ops.set_tuned({"bm": 192, "bn": 192, "bk": 192}, chip="c",
+                      dtype="fp32")
+        assert ops._tuned_kwargs((x, x), {"interpret": True}) == {
+            "interpret": True}
+        np.testing.assert_allclose(np.asarray(ops(x, x)),
+                                   np.asarray(ops.ref(x, x)),
+                                   rtol=2e-4, atol=2e-4)
+    finally:
+        ops.clear_tuned()

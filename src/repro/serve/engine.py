@@ -47,6 +47,16 @@ or just observe.  Preempted requests are requeued with their progress and
 preemption can never change the served token stream — the scenario
 harness (:mod:`repro.scenarios`) asserts exactly that against a
 fault-free golden twin.
+
+**Profiler spans** name what the host is doing while the device waits:
+each iteration of the continuous drains is one ``serve.step`` span
+(``jax.profiler.StepTraceAnnotation``, with ``step_num``) holding the
+flat phases ``serve.hooks``, ``serve.schedule``, ``serve.h2d``,
+``serve.dispatch``, ``serve.select`` (around the sampler's blocking
+``serve.sync``) and ``serve.commit``, in that order.  Iterations that
+admit or finish requests carry their uids as span metadata.  A span
+costs well under a microsecond while no profiler is active, so they are
+always on.
 """
 
 from __future__ import annotations
@@ -60,6 +70,7 @@ from typing import Any, Callable, Deque, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.configs.base import LayerKind, ModelConfig
@@ -87,6 +98,12 @@ def _bucket_width(m: int, cap: int) -> int:
     while w < m:
         w *= 2
     return min(w, cap)
+
+
+def _uids(reqs) -> str:
+    """Request uids as one span-metadata value (the profiler splits
+    metadata on commas, so they are joined by spaces)."""
+    return " ".join(str(r.uid) for r in reqs)
 
 
 def _dev(x: np.ndarray) -> jax.Array:
@@ -117,32 +134,50 @@ def _dev_placed(sharding: NamedSharding):
     return put
 
 
+# The jitted steps are named functions, so that a profiler trace shows each
+# device program under its own name (``jit_serve_decode_step``, ...), not
+# as ``jit__lambda``.
+
+
+def _decode_step_fn(cfg: ModelConfig, block_size: int, kv_dtype: str):
+    def serve_decode_step(p, t, c, pos, bt):
+        return transformer.decode_step_paged(
+            p, cfg, t, c, pos, bt, block_size=block_size, kv_dtype=kv_dtype
+        )
+
+    return serve_decode_step
+
+
+def _prefill_step_fn(cfg: ModelConfig, block_size: int, kv_dtype: str):
+    def serve_prefill_step(p, t, c, pos, bt, lens):
+        return transformer.prefill_step_paged(
+            p, cfg, t, c, pos, bt, lens, block_size=block_size,
+            kv_dtype=kv_dtype
+        )
+
+    return serve_prefill_step
+
+
 @functools.lru_cache(maxsize=None)
 def _jit_decode(cfg: ModelConfig):
     """One compiled dense decode step per ModelConfig (configs are frozen
     dataclasses, so engines serving the same config share the trace)."""
-    return jax.jit(lambda p, t, c: transformer.decode_step(p, cfg, t, c))
+    def serve_decode_dense(p, t, c):
+        return transformer.decode_step(p, cfg, t, c)
+
+    return jax.jit(serve_decode_dense)
 
 
 @functools.lru_cache(maxsize=None)
 def _jit_decode_paged(cfg: ModelConfig, block_size: int, kv_dtype: str):
-    return jax.jit(
-        lambda p, t, c, pos, bt: transformer.decode_step_paged(
-            p, cfg, t, c, pos, bt, block_size=block_size, kv_dtype=kv_dtype
-        )
-    )
+    return jax.jit(_decode_step_fn(cfg, block_size, kv_dtype))
 
 
 @functools.lru_cache(maxsize=None)
 def _jit_prefill_paged(cfg: ModelConfig, block_size: int, kv_dtype: str):
     """Fused chunked-prefill step (chunk width is baked into the token
     array's shape, so each (config, block_size, chunk) traces once)."""
-    return jax.jit(
-        lambda p, t, c, pos, bt, lens: transformer.prefill_step_paged(
-            p, cfg, t, c, pos, bt, lens, block_size=block_size,
-            kv_dtype=kv_dtype
-        )
-    )
+    return jax.jit(_prefill_step_fn(cfg, block_size, kv_dtype))
 
 
 @functools.lru_cache(maxsize=1)
@@ -197,17 +232,12 @@ def _sharded_jits(cfg: ModelConfig, batch: int, max_len: int,
         transformer.slot_state(cache_struct), mesh
     )
     decode = jax.jit(
-        lambda p, t, c, pos, bt: transformer.decode_step_paged(
-            p, cfg, t, c, pos, bt, block_size=block_size, kv_dtype=kv_dtype
-        ),
+        _decode_step_fn(cfg, block_size, kv_dtype),
         in_shardings=(p_sh, tok, cache_sh, rep, rep),
         out_shardings=(rep, cache_sh),
     )
     prefill = jax.jit(
-        lambda p, t, c, pos, bt, lens: transformer.prefill_step_paged(
-            p, cfg, t, c, pos, bt, lens, block_size=block_size,
-            kv_dtype=kv_dtype
-        ),
+        _prefill_step_fn(cfg, block_size, kv_dtype),
         in_shardings=(p_sh, tok, cache_sh, rep, rep, rep),
         out_shardings=(rep, cache_sh),
     )
@@ -623,6 +653,76 @@ class ServeEngine:
             req.first_token_s = time.time()
             req.first_token_step = self.steps  # the call that produced it
 
+    def _admit(self, reset_mask) -> List[Request]:
+        """Refill every free slot of the live drain from the queue NOW —
+        the lane is re-predicated, not idled until a wave drains.  Each
+        admitted slot starts at position 0 with no blocks, is marked for a
+        state reset, and is fed its first prompt token (the chunked drain
+        rebuilds its whole feed every step).  Returns the admitted
+        requests."""
+        live = self._live
+        slot_req = live["slot_req"]
+        admitted = []
+        for b in range(self.max_batch):
+            if slot_req[b] is None and self.queue:
+                r = self.queue.popleft()
+                slot_req[b] = r
+                if r.started_s is None:
+                    r.started_s = time.time()
+                live["positions"][b] = 0
+                live["block_tables"][b] = 0
+                live["tokens"][b, 0] = r.prompt[0]
+                reset_mask[b] = True
+                admitted.append(r)
+        return admitted
+
+    def _free_slot(self, b: int) -> None:
+        """Empty slot ``b`` of the live drain.  Its blocks are decref'd,
+        never freed: a prefix-shared block may still back another slot's
+        cache — it returns to the free list only at refcount 0 (LIFO: the
+        next admission reuses this request's blocks first)."""
+        live = self._live
+        block_tables, pool = live["block_tables"], live["pool"]
+        for j in range(block_tables.shape[1]):
+            if block_tables[b, j] != 0:
+                pool.decref(int(block_tables[b, j]))
+        block_tables[b] = 0
+        live["positions"][b] = 0
+        live["tokens"][b] = 0
+        live["slot_req"][b] = None
+
+    def _map_chunk(self, cache, pool: BlockPool, block_tables, b: int,
+                   r: Request, t0: int, n_b: int):
+        """Map the blocks that slot ``b``'s rows ``t0 .. t0 + n_b - 1`` land
+        in (with sharing on, acquire() may return another slot's block
+        holding the same exact prompt chain), and copy-on-write any block
+        receiving a generated-token row while other slots still reference
+        it.  Returns the cache, which a COW copy rebinds."""
+        bs = self.block_size
+        last = (t0 + n_b - 1) // bs
+        for j in range(t0 // bs, last + 1):
+            if block_tables[b, j] == 0:
+                blk = pool.acquire(r.prompt, j)
+                block_tables[b, j] = blk
+                self.block_history.setdefault(r.uid, []).append(blk)
+        gen_from = max(t0, len(r.prompt))
+        if gen_from < t0 + n_b:
+            for j in range(gen_from // bs, last + 1):
+                old = int(block_tables[b, j])
+                if pool.refcount_of(old) > 1:
+                    new = pool.cow(old)
+                    cache = self._copy_block(
+                        cache, jnp.int32(old), jnp.int32(new)
+                    )
+                    block_tables[b, j] = new
+                    self.block_history.setdefault(r.uid, []).append(new)
+                # in-place generated rows land from max(gen_from, j*bs)
+                # onward in this block: trim any registry key claiming them
+                pool.note_generated_write(
+                    int(block_tables[b, j]), max(gen_from, j * bs) % bs
+                )
+        return cache
+
     def preempt(self, uid: Optional[int] = None) -> Optional[int]:
         """Evict one in-flight request from its slot (continuous only).
 
@@ -642,7 +742,6 @@ class ServeEngine:
                 "continuous scheduler is draining"
             )
         slot_req, positions = live["slot_req"], live["positions"]
-        block_tables, pool = live["block_tables"], live["pool"]
         if uid is not None:
             picks = [b for b, r in enumerate(slot_req)
                      if r is not None and r.uid == uid]
@@ -657,15 +756,7 @@ class ServeEngine:
         req = slot_req[b]
         # replay budget: the resumed run re-spends prompt + generated steps
         self._submitted_work += len(req.prompt) + req.max_new_tokens
-        # decref, never free: a prefix-shared block may still back another
-        # slot's cache — it returns to the free list only at refcount 0
-        for j in range(block_tables.shape[1]):
-            if block_tables[b, j] != 0:
-                pool.decref(int(block_tables[b, j]))
-        block_tables[b] = 0
-        positions[b] = 0
-        live["tokens"][b, :] = 0
-        slot_req[b] = None
+        self._free_slot(b)
         self.queue.appendleft(req)
         self.preemptions += 1
         return req.uid
@@ -759,114 +850,89 @@ class ServeEngine:
 
         try:
             while True:
-                pending = self._call_hooks(
-                    busy=any(r is not None for r in slot_req)
-                )
-                # refill: finished slots take the next queued request NOW —
-                # the lane is re-predicated, not idled until a wave drains
-                for b in range(B):
-                    if slot_req[b] is None and self.queue:
-                        r = self.queue.popleft()
-                        slot_req[b] = r
-                        if r.started_s is None:
-                            r.started_s = time.time()
-                        positions[b] = 0
-                        block_tables[b] = 0
-                        tokens[b, 0] = r.prompt[0]
-                        reset_mask[b] = True
-                if all(r is None for r in slot_req):
-                    if not pending:
-                        break
-                    idle_spins += 1  # hooks promise work; let them deliver
-                    if idle_spins > _MAX_IDLE_SPINS:
-                        raise RuntimeError(
-                            "step hooks report pending work but never submit"
+                with StepTraceAnnotation("serve.step", step_num=self.steps):
+                    with TraceAnnotation("serve.hooks"):
+                        pending = self._call_hooks(
+                            busy=any(r is not None for r in slot_req)
                         )
-                    continue
-                idle_spins = 0
-                # exact occupancy bound: a request holds its slot for at
-                # most prompt + max_new - 1 steps (replays re-budgeted at
-                # preemption), so submitted work is a hard cap
-                budget = (max_steps if max_steps is not None
-                          else self._submitted_work + B)
-                if self.steps >= budget:
-                    raise RuntimeError("serve loop did not drain")
-                # allocate the write block for any slot whose position entered
-                # an unmapped logical block (covers fresh admissions at 0 too);
-                # with sharing on, acquire() may return another slot's block
-                # holding the same exact prompt chain instead of a fresh one
-                for b, r in enumerate(slot_req):
-                    if r is not None:
-                        j = positions[b] // bs
-                        if block_tables[b, j] == 0:
-                            blk = pool.acquire(r.prompt, j)
-                            block_tables[b, j] = blk
-                            self.block_history.setdefault(r.uid, []).append(blk)
-                        # copy-on-write: a generated-token row diverges the
-                        # block's content, so a block other slots still
-                        # reference gets a private copy first (prompt rows
-                        # write through — sharers write identical bytes)
-                        if positions[b] >= len(r.prompt):
-                            if pool.refcount_of(
-                                    int(block_tables[b, j])) > 1:
-                                old = int(block_tables[b, j])
-                                new = pool.cow(old)
-                                cache = self._copy_block(
-                                    cache, jnp.int32(old), jnp.int32(new)
+                    with TraceAnnotation("serve.schedule") as span:
+                        admitted = self._admit(reset_mask)
+                        if admitted:
+                            span.set_metadata(admitted=_uids(admitted))
+                        if all(r is None for r in slot_req):
+                            if not pending:
+                                break
+                            idle_spins += 1  # hooks promise work
+                            if idle_spins > _MAX_IDLE_SPINS:
+                                raise RuntimeError(
+                                    "step hooks report pending work but "
+                                    "never submit"
                                 )
-                                block_tables[b, j] = new
-                                self.block_history.setdefault(
-                                    r.uid, []
-                                ).append(new)
-                            # in-place generated write: any registry key
-                            # claiming this row or beyond is now stale —
-                            # trim it before a later prompt can match it
-                            pool.note_generated_write(
-                                int(block_tables[b, j]),
-                                int(positions[b]) % bs,
-                            )
-                if self._has_state and reset_mask.any():
-                    cache = self._reset_slots(cache, self._dev(reset_mask))
-                reset_mask[:] = False
-
-                self._note_busy(r is not None for r in slot_req)
-                logits, cache = self._decode_paged(
-                    self.params, self._dev_tok(tokens), cache,
-                    self._dev(positions), self._dev(block_tables),
-                )
-                self.steps += 1
-                nxt = self._sampler.select(logits, slot_req)[:, 0]
-                for b, r in enumerate(slot_req):
-                    if r is None:
-                        continue
-                    t = int(positions[b])
-                    positions[b] = t + 1
-                    if t + 1 < len(r.prompt):
-                        tokens[b, 0] = r.prompt[t + 1]  # still consuming prompt
-                        continue
-                    gi = t + 1 - len(r.prompt)
-                    if gi < len(r.generated):
-                        # replay after preemption: this token was already
-                        # served — feed it back, never re-append
-                        tokens[b, 0] = r.generated[gi]
-                        continue
-                    tok = int(nxt[b])
-                    self._note_first_token(r)
-                    r.generated.append(tok)
-                    tokens[b, 0] = tok
-                    if (len(r.generated) >= r.max_new_tokens
-                            or tok == r.eos_id):
-                        self._finish(r)
-                        # release the slot's blocks (LIFO: the next admission
-                        # reuses this request's blocks first); shared blocks
-                        # survive under their other referents' refcounts
-                        for j in range(nb_slot):
-                            if block_tables[b, j] != 0:
-                                pool.decref(int(block_tables[b, j]))
-                        block_tables[b] = 0
-                        positions[b] = 0
-                        tokens[b, 0] = 0
-                        slot_req[b] = None
+                            continue
+                        idle_spins = 0
+                        # exact occupancy bound: a request holds its slot
+                        # for at most prompt + max_new - 1 steps (replays
+                        # re-budgeted at preemption), so submitted work is
+                        # a hard cap
+                        budget = (max_steps if max_steps is not None
+                                  else self._submitted_work + B)
+                        if self.steps >= budget:
+                            raise RuntimeError("serve loop did not drain")
+                        # map the write block of any slot whose position
+                        # entered an unmapped logical block (covers fresh
+                        # admissions at 0 too) and copy-on-write a shared
+                        # block before a generated-token row diverges it
+                        # (prompt rows write through — sharers write
+                        # identical bytes)
+                        for b, r in enumerate(slot_req):
+                            if r is not None:
+                                cache = self._map_chunk(
+                                    cache, pool, block_tables, b, r,
+                                    int(positions[b]), 1)
+                        if self._has_state and reset_mask.any():
+                            cache = self._reset_slots(
+                                cache, self._dev(reset_mask))
+                        reset_mask[:] = False
+                        self._note_busy(r is not None for r in slot_req)
+                    with TraceAnnotation("serve.h2d"):
+                        tok_d = self._dev_tok(tokens)
+                        pos_d = self._dev(positions)
+                        bt_d = self._dev(block_tables)
+                    with TraceAnnotation("serve.dispatch"):
+                        logits, cache = self._decode_paged(
+                            self.params, tok_d, cache, pos_d, bt_d)
+                        self.steps += 1
+                    with TraceAnnotation("serve.select"):
+                        nxt = self._sampler.select(logits, slot_req)[:, 0]
+                    with TraceAnnotation("serve.commit") as span:
+                        finished = []
+                        for b, r in enumerate(slot_req):
+                            if r is None:
+                                continue
+                            t = int(positions[b])
+                            positions[b] = t + 1
+                            if t + 1 < len(r.prompt):
+                                # still consuming prompt
+                                tokens[b, 0] = r.prompt[t + 1]
+                                continue
+                            gi = t + 1 - len(r.prompt)
+                            if gi < len(r.generated):
+                                # replay after preemption: this token was
+                                # already served — feed it back, never
+                                # re-append
+                                tokens[b, 0] = r.generated[gi]
+                                continue
+                            tok = int(nxt[b])
+                            self._note_first_token(r)
+                            r.generated.append(tok)
+                            tokens[b, 0] = tok
+                            if (len(r.generated) >= r.max_new_tokens
+                                    or tok == r.eos_id):
+                                self._finish(r)
+                                self._free_slot(b)
+                                finished.append(r)
+                        if finished:
+                            span.set_metadata(finished=_uids(finished))
         finally:
             self._absorb_pool(pool)
             self._live = None
@@ -914,150 +980,133 @@ class ServeEngine:
 
         try:
             while True:
-                pending = self._call_hooks(
-                    busy=any(r is not None for r in slot_req)
-                )
-                for b in range(B):
-                    if slot_req[b] is None and self.queue:
-                        r = self.queue.popleft()
-                        slot_req[b] = r
-                        if r.started_s is None:
-                            r.started_s = time.time()
-                        positions[b] = 0
-                        block_tables[b] = 0
-                        reset_mask[b] = True
-                if all(r is None for r in slot_req):
-                    if not pending:
-                        break
-                    idle_spins += 1  # hooks promise work; let them deliver
-                    if idle_spins > _MAX_IDLE_SPINS:
-                        raise RuntimeError(
-                            "step hooks report pending work but never submit"
+                with StepTraceAnnotation("serve.step", step_num=self.steps):
+                    with TraceAnnotation("serve.hooks"):
+                        pending = self._call_hooks(
+                            busy=any(r is not None for r in slot_req)
                         )
-                    continue
-                idle_spins = 0
-                # same exact occupancy bound as the token-by-token drain: a
-                # chunked step never advances a slot by less than one token
-                # unless budget-stalled, and at least one slot advances
-                budget = (max_steps if max_steps is not None
-                          else self._submitted_work + B)
-                if self.steps >= budget:
-                    raise RuntimeError("serve loop did not drain")
-                # admission: hand each slot its next known tokens under the
-                # per-step prefill budget, and map the blocks they land in
-                tokens[:] = 0
-                lengths[:] = 0
-                budget_left = (self.prefill_budget
-                               if self.prefill_budget is not None else B * C)
-                for b, r in enumerate(slot_req):
-                    if r is None:
-                        continue
-                    t0 = int(positions[b])
-                    known = len(r.prompt) + len(r.generated)
-                    n_rem = known - t0
-                    if n_rem <= 1:
-                        n_b = 1  # decode: always advances, never budgeted
-                    else:
-                        n_b = min(C, n_rem, budget_left)
-                        budget_left -= n_b
-                    if n_b <= 0:
-                        continue  # prefill stalled by budget this step
-                    for c in range(n_b):
-                        p = t0 + c
-                        tokens[b, c] = (
-                            r.prompt[p] if p < len(r.prompt)
-                            else r.generated[p - len(r.prompt)]
-                        )
-                    lengths[b] = n_b
-                    for j in range(t0 // bs, (t0 + n_b - 1) // bs + 1):
-                        if block_tables[b, j] == 0:
-                            blk = pool.acquire(r.prompt, j)
-                            block_tables[b, j] = blk
-                            self.block_history.setdefault(
-                                r.uid, []
-                            ).append(blk)
-                    # copy-on-write for any block receiving a generated-token
-                    # row this step while other slots still reference it
-                    gen_from = max(t0, len(r.prompt))
-                    if gen_from < t0 + n_b:
-                        for j in range(gen_from // bs,
-                                       (t0 + n_b - 1) // bs + 1):
-                            old = int(block_tables[b, j])
-                            if pool.refcount_of(old) > 1:
-                                new = pool.cow(old)
-                                cache = self._copy_block(
-                                    cache, jnp.int32(old), jnp.int32(new)
+                    with TraceAnnotation("serve.schedule") as span:
+                        admitted = self._admit(reset_mask)
+                        if admitted:
+                            span.set_metadata(admitted=_uids(admitted))
+                        if all(r is None for r in slot_req):
+                            if not pending:
+                                break
+                            idle_spins += 1  # hooks promise work
+                            if idle_spins > _MAX_IDLE_SPINS:
+                                raise RuntimeError(
+                                    "step hooks report pending work but "
+                                    "never submit"
                                 )
-                                block_tables[b, j] = new
-                                self.block_history.setdefault(
-                                    r.uid, []
-                                ).append(new)
-                            # in-place generated rows land from
-                            # max(gen_from, j*bs) onward in this block:
-                            # trim any registry key claiming them
-                            pool.note_generated_write(
-                                int(block_tables[b, j]),
-                                max(gen_from, j * bs) % bs,
-                            )
-                if self._has_state and reset_mask.any():
-                    cache = self._reset_slots(cache, self._dev(reset_mask))
-                reset_mask[:] = False
-
-                self._note_busy(lengths > 0)
-                # disaggregated dispatch: a step with no prefill chunk in
-                # flight (every busy slot advances exactly 1 token) runs
-                # the native 1-wide decode step — decode never pays a
-                # chunk-wide scan; steps that DO carry prefill run the
-                # scan sliced to the smallest power-of-two bucket >= the
-                # widest chunk, so partial chunks don't burn masked cells.
-                # Both are bitwise safe: decode_step_paged is the C=1 cell
-                # of prefill_step_paged, a masked cell is identity on the
-                # cache, and a budget-stalled slot (lengths == 0 with
-                # mapped blocks) always takes the masked scan path so it
-                # is never fed a garbage token.
-                pure_decode = all(
-                    lengths[b] == 1 for b, r in enumerate(slot_req)
-                    if r is not None
-                )
-                if pure_decode:
-                    logits, cache = self._decode_paged(
-                        self.params, self._dev_tok(tokens[:, :1]), cache,
-                        self._dev(positions), self._dev(block_tables),
+                            continue
+                        idle_spins = 0
+                        # same exact occupancy bound as the token-by-token
+                        # drain: a chunked step never advances a slot by
+                        # less than one token unless budget-stalled, and at
+                        # least one slot advances
+                        budget = (max_steps if max_steps is not None
+                                  else self._submitted_work + B)
+                        if self.steps >= budget:
+                            raise RuntimeError("serve loop did not drain")
+                        # admission: hand each slot its next known tokens
+                        # under the per-step prefill budget, and map the
+                        # blocks they land in
+                        tokens[:] = 0
+                        lengths[:] = 0
+                        budget_left = (self.prefill_budget
+                                       if self.prefill_budget is not None
+                                       else B * C)
+                        for b, r in enumerate(slot_req):
+                            if r is None:
+                                continue
+                            t0 = int(positions[b])
+                            known = len(r.prompt) + len(r.generated)
+                            n_rem = known - t0
+                            if n_rem <= 1:
+                                n_b = 1  # decode: always advances, unbudgeted
+                            else:
+                                n_b = min(C, n_rem, budget_left)
+                                budget_left -= n_b
+                            if n_b <= 0:
+                                continue  # prefill stalled by budget
+                            for c in range(n_b):
+                                p = t0 + c
+                                tokens[b, c] = (
+                                    r.prompt[p] if p < len(r.prompt)
+                                    else r.generated[p - len(r.prompt)]
+                                )
+                            lengths[b] = n_b
+                            cache = self._map_chunk(
+                                cache, pool, block_tables, b, r, t0, n_b)
+                        if self._has_state and reset_mask.any():
+                            cache = self._reset_slots(
+                                cache, self._dev(reset_mask))
+                        reset_mask[:] = False
+                        self._note_busy(lengths > 0)
+                    # disaggregated dispatch: a step with no prefill chunk
+                    # in flight (every busy slot advances exactly 1 token)
+                    # runs the native 1-wide decode step — decode never
+                    # pays a chunk-wide scan; steps that DO carry prefill
+                    # run the scan sliced to the smallest power-of-two
+                    # bucket >= the widest chunk, so partial chunks don't
+                    # burn masked cells.  Both are bitwise safe:
+                    # decode_step_paged is the C=1 cell of
+                    # prefill_step_paged, a masked cell is identity on the
+                    # cache, and a budget-stalled slot (lengths == 0 with
+                    # mapped blocks) always takes the masked scan path so
+                    # it is never fed a garbage token.
+                    pure_decode = all(
+                        lengths[b] == 1 for b, r in enumerate(slot_req)
+                        if r is not None
                     )
-                else:
-                    w = _bucket_width(int(lengths.max()), C)
-                    logits, cache = self._prefill_paged(
-                        self.params, self._dev_tok(tokens[:, :w]), cache,
-                        self._dev(positions), self._dev(block_tables),
-                        self._dev(lengths),
-                    )
-                self.steps += 1
-                # one transfer: select from each slot's LAST fed row (only
-                # slots that just consumed their final known token use it)
-                last = jnp.maximum(jnp.asarray(lengths) - 1, 0)
-                rows = logits[jnp.arange(B), last][:, None]
-                nxt = self._sampler.select(rows, slot_req)[:, 0]
-                for b, r in enumerate(slot_req):
-                    if r is None or lengths[b] == 0:
-                        continue
-                    n_b = int(lengths[b])
-                    t0 = int(positions[b])
-                    positions[b] = t0 + n_b
-                    if t0 + n_b < len(r.prompt) + len(r.generated):
-                        continue  # still prefilling (or replaying)
-                    tok = int(nxt[b])
-                    self._note_first_token(r)
-                    r.generated.append(tok)
-                    if (len(r.generated) >= r.max_new_tokens
-                            or tok == r.eos_id):
-                        self._finish(r)
-                        for j in range(nb_slot):
-                            if block_tables[b, j] != 0:
-                                pool.decref(int(block_tables[b, j]))
-                        block_tables[b] = 0
-                        positions[b] = 0
-                        tokens[b, :] = 0
-                        slot_req[b] = None
+                    with TraceAnnotation("serve.h2d"):
+                        w = (1 if pure_decode
+                             else _bucket_width(int(lengths.max()), C))
+                        tok_d = self._dev_tok(tokens[:, :w])
+                        pos_d = self._dev(positions)
+                        bt_d = self._dev(block_tables)
+                        # one copy of the lengths per step: a scan step
+                        # takes them here; a decode step does not, and
+                        # copies them for the row gather after its dispatch,
+                        # while the device runs the step
+                        len_d = None if pure_decode else self._dev(lengths)
+                    with TraceAnnotation("serve.dispatch"):
+                        if pure_decode:
+                            logits, cache = self._decode_paged(
+                                self.params, tok_d, cache, pos_d, bt_d)
+                        else:
+                            logits, cache = self._prefill_paged(
+                                self.params, tok_d, cache, pos_d, bt_d, len_d)
+                        self.steps += 1
+                    with TraceAnnotation("serve.select"):
+                        # one transfer: select from each slot's LAST fed row
+                        # (only slots that just consumed their final known
+                        # token use it)
+                        if len_d is None:
+                            len_d = self._dev(lengths)
+                        last = jnp.maximum(len_d - 1, 0)
+                        rows = logits[jnp.arange(B), last][:, None]
+                        nxt = self._sampler.select(rows, slot_req)[:, 0]
+                    with TraceAnnotation("serve.commit") as span:
+                        finished = []
+                        for b, r in enumerate(slot_req):
+                            if r is None or lengths[b] == 0:
+                                continue
+                            n_b = int(lengths[b])
+                            t0 = int(positions[b])
+                            positions[b] = t0 + n_b
+                            if t0 + n_b < len(r.prompt) + len(r.generated):
+                                continue  # still prefilling (or replaying)
+                            tok = int(nxt[b])
+                            self._note_first_token(r)
+                            r.generated.append(tok)
+                            if (len(r.generated) >= r.max_new_tokens
+                                    or tok == r.eos_id):
+                                self._finish(r)
+                                self._free_slot(b)
+                                finished.append(r)
+                        if finished:
+                            span.set_metadata(finished=_uids(finished))
         finally:
             self._absorb_pool(pool)
             self._live = None

@@ -28,6 +28,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 @functools.lru_cache(maxsize=None)
@@ -35,7 +36,10 @@ def _jit_greedy(vocab: int):
     """Argmax over the unpadded vocab for every logit row — exactly the
     expression the schedulers used inline, so temp=0 streams are bitwise
     unchanged by the refactor."""
-    return jax.jit(lambda rows: jnp.argmax(rows[..., :vocab], axis=-1))
+    def serve_select_greedy(rows):
+        return jnp.argmax(rows[..., :vocab], axis=-1)
+
+    return jax.jit(serve_select_greedy)
 
 
 @functools.lru_cache(maxsize=None)
@@ -47,7 +51,7 @@ def _jit_sample(vocab: int, temperature: float, top_k: int, seed: int):
     to generation index ``idx0[b] + i``, which is what makes multi-token
     (speculative) windows read the same stream as one-token decode.
     """
-    def fn(rows, uids, idx0):
+    def serve_select_sample(rows, uids, idx0):
         B, W, _ = rows.shape
         logits = rows[..., :vocab].astype(jnp.float32) / temperature
         if 0 < top_k < vocab:
@@ -66,7 +70,7 @@ def _jit_sample(vocab: int, temperature: float, top_k: int, seed: int):
         )
         return toks.reshape(B, W)
 
-    return jax.jit(fn)
+    return jax.jit(serve_select_sample)
 
 
 class SlotSampler:
@@ -112,17 +116,19 @@ class SlotSampler:
         is appended).  Rows of idle/irrelevant slots are selected too
         and simply discarded by the caller; their keys can never collide
         with a live stream's.  Returns ``(B, W)`` int64 host tokens via
-        a single device transfer.
+        a single device transfer, the one place the serve loop waits on
+        the device (span ``serve.sync``).
         """
         if self.greedy:
-            return np.asarray(self._fn(rows))
-        uids = np.array(
-            [0 if r is None else int(r.uid) for r in reqs], np.int64
-        ).astype(np.uint32)
-        idx0 = np.array(
-            [0 if r is None else len(r.generated) + offset for r in reqs],
-            np.int64,
-        ).astype(np.uint32)
-        return np.asarray(
-            self._fn(rows, jnp.asarray(uids), jnp.asarray(idx0))
-        )
+            ids = self._fn(rows)
+        else:
+            uids = np.array(
+                [0 if r is None else int(r.uid) for r in reqs], np.int64
+            ).astype(np.uint32)
+            idx0 = np.array(
+                [0 if r is None else len(r.generated) + offset for r in reqs],
+                np.int64,
+            ).astype(np.uint32)
+            ids = self._fn(rows, jnp.asarray(uids), jnp.asarray(idx0))
+        with TraceAnnotation("serve.sync"):
+            return np.asarray(ids)

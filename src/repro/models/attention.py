@@ -140,3 +140,70 @@ def attention_decode(params, cfg, x, cache_k, cache_v, pos):
     out = (ctx_old * w_old[..., None] + v_new5 * w_new[..., None]) / denom[..., None]
     out = out.astype(x.dtype).reshape(B, 1, h * hd)
     return layers.dense(params["wo"], out), k_new, v_new
+
+
+def attention_chunk(params, cfg, x, cache_k, cache_v, pos, stored):
+    """Causal attention of a C-token chunk per slot — READ-ONLY on the cache.
+
+    x: (B, C, d); cache_k/v: (B, S, KV, D) logical views of each slot's
+    cache; pos: (B,) int32 cache lengths before the chunk, so row ``c`` of
+    slot ``b`` sits at ``pos[b] + c``.  Each row attends over its slot's
+    cache prefix (``s < pos[b]``) and the chunk's rows ``c' <= c``, merged
+    by the same two-way online softmax as :func:`attention_decode` (and
+    for the same reason: no concatenate along the cache's seq axis).
+
+    ``stored`` maps a (B, C, KV, D) slice to what the cache will read back
+    once it is committed (the pool dtype's round trip).  Rows ``c' < c``
+    use it, the diagonal does not: that is what a token-by-token decode of
+    the same chunk sees, so the two agree to float reassociation at every
+    pool dtype.  Returns (y (B, C, d), k_new, v_new (B, C, KV, D)).
+    """
+    B, C, _ = x.shape
+    h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    g = h // kv
+    pos_b = jnp.asarray(pos, jnp.int32)
+    row = jnp.arange(C, dtype=jnp.int32)
+    q, k_new, v_new = _project_qkv(params, cfg, x, pos_b[:, None] + row)
+    q = q.reshape(B, C, kv, g, hd)
+    scale = 1.0 / math.sqrt(hd)
+    S = cache_k.shape[1]
+    s_old = jnp.einsum(
+        "bqkgd,bskd->bkgqs", q, cache_k, preferred_element_type=jnp.float32
+    ) * scale                                       # (B,KV,G,C,S)
+    mask = jnp.arange(S)[None, :] < pos_b[:, None]  # (B, S)
+    s_old = jnp.where(mask[:, None, None, None, :], s_old, NEG_INF)
+    k_in, v_in = stored(k_new), stored(v_new)
+    s_in = jnp.einsum(
+        "bqkgd,bckd->bkgqc", q, k_in, preferred_element_type=jnp.float32
+    ) * scale                                       # (B,KV,G,C,C)
+    s_self = jnp.einsum(
+        "bqkgd,bqkd->bkgq", q, k_new, preferred_element_type=jnp.float32
+    ) * scale
+    diag = row[:, None] == row[None, :]
+    s_in = jnp.where(diag, s_self[..., None], s_in)
+    s_in = jnp.where(row[:, None] >= row[None, :], s_in, NEG_INF)
+
+    m_old = s_old.max(axis=-1)                      # (B,KV,G,C)
+    p_old = jnp.exp(s_old - m_old[..., None])
+    l_old = p_old.sum(axis=-1)
+    ctx_old = jnp.einsum(
+        "bkgqs,bskd->bkgqd", p_old.astype(cache_v.dtype), cache_v,
+        preferred_element_type=jnp.float32,
+    )
+    m_in = s_in.max(axis=-1)                        # diagonal: finite
+    p_in = jnp.exp(s_in - m_in[..., None])
+    l_in = p_in.sum(axis=-1)
+    p_self = jnp.exp(s_self - m_in)                 # p_in's diagonal
+    p_off = jnp.where(diag, 0.0, p_in)
+    v_self = v_new.astype(jnp.float32).transpose(0, 2, 1, 3)[:, :, None]
+    ctx_in = jnp.einsum(
+        "bkgqc,bckd->bkgqd", p_off.astype(v_in.dtype), v_in,
+        preferred_element_type=jnp.float32,
+    ) + v_self * p_self[..., None]
+    m = jnp.maximum(m_old, m_in)
+    w_old = jnp.exp(m_old - m)                      # 0 when cache empty
+    w_in = jnp.exp(m_in - m)
+    denom = l_old * w_old + l_in * w_in
+    out = (ctx_old * w_old[..., None] + ctx_in * w_in[..., None]) / denom[..., None]
+    out = out.transpose(0, 3, 1, 2, 4).astype(x.dtype).reshape(B, C, h * hd)
+    return layers.dense(params["wo"], out), k_new, v_new

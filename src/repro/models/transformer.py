@@ -722,6 +722,28 @@ def _commit_slot(c_slot, slot_deltas, flat_idx, stacked: bool,
     return out
 
 
+def _paged_view(c_slot, block_tables, kv_dtype: str, compute):
+    """Gather logical per-slot views of one layer-slot's sequence pools,
+    dequantizing quantized storage back to compute precision."""
+    out = {}
+    for k, leaf in c_slot.items():
+        if k.endswith("_scale"):
+            continue  # consumed by its data leaf's dequant below
+        if k not in _SEQ_CACHE_KEYS:
+            out[k] = leaf
+            continue
+        g = _gather_paged(leaf, block_tables)
+        if kv_dtype == "int8":
+            s = _gather_paged(c_slot[k + "_scale"], block_tables)
+            g = g.astype(compute) * s.reshape(
+                s.shape + (1,) * (g.ndim - s.ndim)
+            ).astype(compute)
+        elif kv_dtype == "bf16":
+            g = g.astype(compute)
+        out[k] = g
+    return out
+
+
 def _paged_token_step(
     params,
     cfg: ModelConfig,
@@ -762,25 +784,7 @@ def _paged_token_step(
     x = layers.embed(params["embed"], tokens).astype(compute)
 
     def _view(c_slot):
-        """Gather logical per-slot views of this layer's sequence pools,
-        dequantizing quantized storage back to compute precision."""
-        out = {}
-        for k, leaf in c_slot.items():
-            if k.endswith("_scale"):
-                continue  # consumed by its data leaf's dequant below
-            if k not in _SEQ_CACHE_KEYS:
-                out[k] = leaf
-                continue
-            g = _gather_paged(leaf, block_tables)
-            if kv_dtype == "int8":
-                s = _gather_paged(c_slot[k + "_scale"], block_tables)
-                g = g.astype(compute) * s.reshape(
-                    s.shape + (1,) * (g.ndim - s.ndim)
-                ).astype(compute)
-            elif kv_dtype == "bf16":
-                g = g.astype(compute)
-            out[k] = g
-        return out
+        return _paged_view(c_slot, block_tables, kv_dtype, compute)
 
     new_cache: Dict[str, Any] = {"blocks": None}
     if "first_block" in params:
@@ -891,6 +895,111 @@ def prefill_step_paged(
         body, cache, (tokens.T, jnp.arange(C, dtype=jnp.int32))
     )
     return jnp.transpose(logits, (1, 0, 2)), cache
+
+
+def chunk_parallel(cfg: ModelConfig) -> bool:
+    """True where :func:`prefill_chunk_paged` can serve a chunk: every
+    layer is dense GQA attention with a dense FFN.  SSM slots need
+    in-chunk recurrence, MLA its own latent chunk maths, and MoE capacity
+    drops tokens by how many rows are batched (so a batched pass would
+    drop others); those keep :func:`prefill_step_paged`.  With ``moe``
+    None there is no ``first_block`` either."""
+    return (all(k == LayerKind.ATTN for k in cfg.superblock)
+            and cfg.mla is None and cfg.moe is None)
+
+
+def _as_stored(a, cfg: ModelConfig, kv_dtype: str):
+    """A fresh key/value slice as a ``kv_dtype`` pool reads it back once
+    committed (int8: the commit's per-row quantization)."""
+    compute = jnp.dtype(cfg.compute_dtype)
+    if kv_dtype == "int8":
+        q, s = _quantize_token(a, stacked=False)
+        return q.astype(compute) * s.reshape(
+            s.shape + (1,) * (a.ndim - s.ndim)).astype(compute)
+    return a.astype(_pool_dtype(cfg, kv_dtype)).astype(compute)
+
+
+def prefill_chunk_paged(
+    params,
+    cfg: ModelConfig,
+    tokens: jax.Array,
+    cache: Dict[str, Any],
+    positions: jax.Array,
+    block_tables: jax.Array,
+    lengths: jax.Array,
+    *,
+    block_size: int,
+    kv_dtype: str = "f32",
+) -> Tuple[jax.Array, Dict[str, Any]]:
+    """:func:`prefill_step_paged`'s contract in ONE pass through the layers.
+
+    Same arguments and results: slot ``b``'s row ``c`` sits at
+    ``positions[b] + c`` and is active iff ``c < lengths[b]``; logits
+    (B, C, vocab_padded) fp32 come back for every row.  Each layer runs
+    its projections and FFN on all B x C rows at once, so every weight is
+    read once per step instead of C times, and attends each row over its
+    slot's cache prefix plus the chunk's own causal rows
+    (:func:`~repro.models.attention.attention_chunk`).  The layers' key
+    and value rows commit after the layer scan in one scatter; inactive
+    rows land in :data:`NULL_BLOCK`.  Against the scan the sums are
+    reassociated, so logits and committed rows agree to float tolerance,
+    not bitwise.  Only for configs where :func:`chunk_parallel` holds.
+    """
+    if not chunk_parallel(cfg):
+        raise ValueError(f"{cfg.name}: chunk-parallel prefill needs dense "
+                         "GQA attention layers with dense FFNs")
+    B, C = tokens.shape
+    pos0 = positions.astype(jnp.int32)
+    row = jnp.arange(C, dtype=jnp.int32)
+    pos_bc = pos0[:, None] + row                                # (B, C)
+    active = (row < lengths.astype(jnp.int32)[:, None]).reshape(B * C)
+    nb = block_tables.shape[1]
+    blk = jnp.take_along_axis(
+        block_tables, jnp.minimum(pos_bc // block_size, nb - 1), axis=1)
+    flat_idx = jnp.where(
+        active, (blk * block_size + pos_bc % block_size).reshape(B * C),
+        NULL_BLOCK * block_size,
+    )  # (B*C,) pool token index
+    compute = jnp.dtype(cfg.compute_dtype)
+    _, norm_fn = layers.make_norm(cfg)
+    x = layers.embed(params["embed"], tokens).astype(compute)
+
+    def stored(a):
+        return _as_stored(a, cfg, kv_dtype)
+
+    def scan_body(x, inp):
+        p_blk, c_blk = inp
+        deltas = {}
+        for i in range(len(cfg.superblock)):
+            p = p_blk[f"slot{i}"]
+            view = _paged_view(c_blk[f"slot{i}"], block_tables, kv_dtype,
+                               compute)
+            att, k_new, v_new = attention.attention_chunk(
+                p["attn"], cfg, norm_fn(p["norm1"], x), view["k"],
+                view["v"], pos0, stored)
+            x = x + att
+            if "ffn" in p:
+                x = x + layers.swiglu(p["ffn"], norm_fn(p["norm2"], x))
+            deltas[f"slot{i}"] = {"k": k_new, "v": v_new}
+        return x, deltas
+
+    x, deltas = jax.lax.scan(scan_body, x, (params["blocks"], cache["blocks"]))
+    # (nsb, B, C, ...) -> (nsb, B*C, 1, ...): one token row per pool index
+    new_cache: Dict[str, Any] = {"blocks": {
+        slot: _commit_slot(
+            cache["blocks"][slot],
+            {k: d.reshape((d.shape[0], B * C, 1) + d.shape[3:])
+             for k, d in slot_deltas.items()},
+            flat_idx, True, active, kv_dtype)
+        for slot, slot_deltas in deltas.items()
+    }}
+
+    x = norm_fn(params["final_norm"], x)
+    if cfg.tie_embeddings:
+        logits = layers.unembed(params["embed"], x)
+    else:
+        logits = layers.dense(params["lm_head"], x).astype(jnp.float32)
+    return logits, new_cache
 
 
 def prefill(

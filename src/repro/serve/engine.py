@@ -21,11 +21,15 @@ numpy ("slot accounting"); only the pools live on device, and the fused
 step is compiled exactly once per engine.
 
 ``prefill_chunk > 1`` turns on prefill/decode disaggregation: prompts are
-committed up to ``prefill_chunk`` tokens per fused
-:func:`~repro.models.transformer.prefill_step_paged` call (a scan over
-the same per-token cell as decode, so served streams stay bit-identical)
-while in-flight decode slots keep advancing one token per step in the
-SAME fused call.  ``prefill_budget`` caps the total prefill tokens
+committed up to ``prefill_chunk`` tokens per fused call while in-flight
+decode slots keep advancing one token per step in the SAME fused call.
+On dense-attention configs that call is
+:func:`~repro.models.transformer.prefill_chunk_paged`, one pass through
+the layers over every chunk row, which agrees with token-by-token decode
+to float tolerance and gives the same greedy tokens; MoE, MLA, SSM and
+hybrid configs run :func:`~repro.models.transformer.prefill_step_paged`,
+a scan over the same per-token cell as decode, so their served streams
+stay bit-identical.  ``prefill_budget`` caps the total prefill tokens
 admitted per step — decode tokens are never counted against it — so a
 long prompt cannot starve decode latency; time-to-first-token
 (``ttft_p50_s``/``ttft_p95_s``) is the metric this trades against raw
@@ -91,7 +95,7 @@ _MAX_IDLE_SPINS = 100_000
 
 
 def _bucket_width(m: int, cap: int) -> int:
-    """Smallest power-of-two >= m, clamped to cap (chunk-scan widths are
+    """Smallest power-of-two >= m, clamped to cap (prefill-step widths are
     bucketed so each width traces once and partial chunks don't pay for
     the full chunk's masked cells)."""
     w = 1
@@ -149,11 +153,16 @@ def _decode_step_fn(cfg: ModelConfig, block_size: int, kv_dtype: str):
 
 
 def _prefill_step_fn(cfg: ModelConfig, block_size: int, kv_dtype: str):
+    """The chunk-parallel pass where the config allows it
+    (:func:`~repro.models.transformer.chunk_parallel`), else the scan of
+    one-token cells."""
+    step = (transformer.prefill_chunk_paged
+            if transformer.chunk_parallel(cfg)
+            else transformer.prefill_step_paged)
+
     def serve_prefill_step(p, t, c, pos, bt, lens):
-        return transformer.prefill_step_paged(
-            p, cfg, t, c, pos, bt, lens, block_size=block_size,
-            kv_dtype=kv_dtype
-        )
+        return step(p, cfg, t, c, pos, bt, lens, block_size=block_size,
+                    kv_dtype=kv_dtype)
 
     return serve_prefill_step
 
@@ -471,6 +480,10 @@ class ServeEngine:
             self._dev = _dev_placed(rep)
             self._dev_tok = _dev_placed(tok)
         self._has_state = any(k != LayerKind.ATTN for k in cfg.superblock)
+        #: which multi-token step ``_prefill_step_fn`` picked: "chunk" (one
+        #: pass over the chunk's rows) or "scan" (one-token cells)
+        self.prefill_path = ("chunk" if transformer.chunk_parallel(cfg)
+                             else "scan")
         # per-device busy-lane accounting (Eq. 1 one level up): the data
         # axis shards the slot lanes across device groups when divisible;
         # otherwise (and with no mesh) there is a single shard and
@@ -599,7 +612,7 @@ class ServeEngine:
         bt = jnp.zeros((B, self.max_len // self.block_size), jnp.int32)
         if self.prefill_chunk > 1:
             # chunked engines dispatch the native decode step plus one
-            # scan trace per power-of-two bucket width — warm every width
+            # prefill trace per power-of-two bucket width — warm every width
             # the drain can hit so no compile lands inside a request
             w = 2
             while True:
@@ -614,7 +627,7 @@ class ServeEngine:
                 w *= 2
         if self._spec is not None:
             # speculative engines dispatch the (k+1)-wide verification
-            # scan (replay reuses the same trace) and the draft model's
+            # step (replay reuses the same trace) and the draft model's
             # 1-wide step — warm both alongside the native decode step
             out = self._prefill_paged(
                 self.params, jnp.zeros((B, self.spec_k + 1), jnp.int32),
@@ -955,10 +968,11 @@ class ServeEngine:
         admitted under a per-step token budget alongside every decode
         step.  A slot appends a new token only on the step that consumes
         its last known token, from the logits row of that token; all other
-        rows are discarded.  The fused step is
-        :func:`~repro.models.transformer.prefill_step_paged`, a scan over
-        the same per-token cell as decode, so served streams are
-        bit-identical to the token-by-token scheduler.
+        rows are discarded.  The fused step is the engine's
+        ``prefill_path``: the scan over the same per-token cell as decode
+        serves streams bit-identical to the token-by-token scheduler, and
+        the chunk-parallel pass agrees with it to float tolerance (the
+        same greedy tokens).
         """
         B, bs, C = self.max_batch, self.block_size, self.prefill_chunk
         nb_slot = self.max_len // bs
@@ -1046,15 +1060,17 @@ class ServeEngine:
                     # disaggregated dispatch: a step with no prefill chunk
                     # in flight (every busy slot advances exactly 1 token)
                     # runs the native 1-wide decode step — decode never
-                    # pays a chunk-wide scan; steps that DO carry prefill
-                    # run the scan sliced to the smallest power-of-two
-                    # bucket >= the widest chunk, so partial chunks don't
-                    # burn masked cells.  Both are bitwise safe:
-                    # decode_step_paged is the C=1 cell of
-                    # prefill_step_paged, a masked cell is identity on the
-                    # cache, and a budget-stalled slot (lengths == 0 with
-                    # mapped blocks) always takes the masked scan path so
-                    # it is never fed a garbage token.
+                    # pays a chunk-wide step; steps that DO carry prefill
+                    # run the prefill step sliced to the smallest
+                    # power-of-two bucket >= the widest chunk, so partial
+                    # chunks don't burn masked rows.  A masked row is
+                    # identity on live blocks (it commits to the null
+                    # block), and a budget-stalled slot (lengths == 0 with
+                    # mapped blocks) always takes the masked prefill step
+                    # so it is never fed a garbage token.  On the scan
+                    # path the split is bitwise safe (decode_step_paged is
+                    # the C=1 cell of prefill_step_paged); the chunk path
+                    # agrees with it to float tolerance.
                     pure_decode = all(
                         lengths[b] == 1 for b, r in enumerate(slot_req)
                         if r is not None
@@ -1065,7 +1081,7 @@ class ServeEngine:
                         tok_d = self._dev_tok(tokens[:, :w])
                         pos_d = self._dev(positions)
                         bt_d = self._dev(block_tables)
-                        # one copy of the lengths per step: a scan step
+                        # one copy of the lengths per step: a prefill step
                         # takes them here; a decode step does not, and
                         # copies them for the row gather after its dispatch,
                         # while the device runs the step
@@ -1153,6 +1169,7 @@ class ServeEngine:
             "scheduler": self.scheduler,
             "prefill_chunk": self.prefill_chunk,
             "prefill_budget": self.prefill_budget,
+            "prefill_path": self.prefill_path,
             "kv_dtype": self.kv_dtype,
             "share_prefixes": self.share_prefixes,
             # mesh placement: the DxM shape string keys the +mesh<DxM>

@@ -1,10 +1,11 @@
 """Speculative decoding over the paged KV cache.
 
 A small *draft* model proposes up to ``k`` tokens per busy slot; the
-*target* model then scores all of them in ONE fused
-:func:`~repro.models.transformer.prefill_step_paged` call — the same
-scan cell chunked prefill uses, with per-slot ragged ``valid_len``, so
-verification is bit-exact against token-by-token decode.  The longest
+*target* model then scores all of them in ONE fused call of the engine's
+prefill step, with per-slot ragged ``valid_len``: the scan cell chunked
+prefill uses (bit-exact against token-by-token decode) or, on
+dense-attention targets, the chunk-parallel pass (equal to float
+tolerance, the same greedy choices).  The longest
 proposal prefix that matches the target's own (canonical-stream, see
 :mod:`repro.serve.sampling`) choices is accepted, plus the target's one
 correction token; the rejected suffix is undone by rewinding
@@ -17,10 +18,11 @@ lanes, and :func:`repro.core.metrics.acceptance_rate` is the active-lane
 fraction — rejected drafts burn issue slots exactly like predicated-out
 SVE lanes.
 
-Why the streams stay bit-identical to the non-speculative engine at any
+Why the streams stay identical to the non-speculative engine at any
 temperature: both the draft proposals and the target verification read
 the SAME per-``(request, generation_index)`` PRNG streams, and the
 target's choice at index ``i`` is computed from canonical logits
+(bitwise on the scan path, to float tolerance on the chunk path)
 whenever the prefix through ``i-1`` was accepted.  Accepted tokens are
 therefore exactly the tokens the plain engine would have emitted, and a
 rejection merely defers index ``i`` to the next step, where the same

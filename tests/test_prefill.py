@@ -1,18 +1,24 @@
 """Chunked-prefill contract, kernel to engine.
 
-Three layers, one invariant — chunking changes SCHEDULING, never bytes:
+Three layers, one invariant — chunking changes SCHEDULING, not results:
 
 * kernel: ``flash_prefill_paged`` (causal online-softmax over a chunk,
   committing K/V through the paged block tables) matches the dense
   ``prefill_paged_ref`` oracle, commits pools bit-exactly, and ignores
   stale bytes past the chunk frontier (predication, Eq. 1).
-* model: ``prefill_step_paged`` is a scan over the SAME per-token cell as
-  ``decode_step_paged``, so a C-token chunk produces bit-identical logits
-  AND bit-identical paged-cache bytes to C single-token steps — across
-  every serve architecture (dense, GQA, MLA, MoE, SSM, hybrid).
-* engine: chunked serving emits byte-identical token streams to the
-  token-by-token scheduler in strictly fewer fused steps, and the
-  deterministic step-clock TTFT p95 drops on a bimodal prompt mix.
+* model, two paths.  ``prefill_step_paged`` is a scan over the SAME
+  per-token cell as ``decode_step_paged``, so a C-token chunk produces
+  bit-identical logits AND bit-identical paged-cache bytes to C
+  single-token steps — across every serve architecture (dense, GQA, MLA,
+  MoE, SSM, hybrid).  ``prefill_chunk_paged``, the one-pass path the
+  engine picks for dense-attention configs, sums in other orders: its
+  logits and committed rows agree with the scan within ``CHUNK_TOL`` (f32
+  reassociation, justified there), and it leaves every other block's
+  bytes untouched.
+* engine: chunked serving emits the same token streams as the
+  token-by-token scheduler in strictly fewer fused steps (byte-identical
+  by construction on the scan path), and the deterministic step-clock
+  TTFT p95 drops on a bimodal prompt mix.
 """
 
 import jax
@@ -301,6 +307,137 @@ def test_prefill_chunk_bit_equals_token_chain(arch):
             np.asarray(logits_c)[b, plen[b] - 1], last[b],
             err_msg=f"{arch} slot {b} logits")
     _assert_caches_bit_equal(cache_c, cache_t, msg=f"{arch} ")
+
+
+# ---------------------------------------------------------------------------
+# model: prefill_chunk_paged == prefill_step_paged, to float tolerance
+# ---------------------------------------------------------------------------
+
+#: Both paths run the same float32 operations on the same inputs but sum
+#: them in other orders: batched matmuls block their reductions otherwise,
+#: and the chunk merges each softmax over (cache, chunk) where the scan
+#: merges it over (cache + earlier rows, the row itself).  Each sum's
+#: rounding is about eps * sqrt(n) relative (eps 1.2e-7, n <= 128 terms at
+#: the smoke widths): about 1e-6 on these O(1) logits (measured up to
+#: 2.1e-6 over four seeds of every case).  5e-5 leaves a twenty-fold
+#: margin.
+CHUNK_TOL = dict(rtol=5e-5, atol=5e-5)
+
+#: With a bf16 or int8 pool, a key or value element that the two paths
+#: compute 1e-7 apart can sit on a rounding boundary and be stored one
+#: step apart (2**-8 relative in bf16, 1/127 of its row's largest in
+#: int8); later rows of the chunk read it back, which moves their logits
+#: by about that step times the element's attention weight (measured up
+#: to 1.0e-4 for bf16 and 2.8e-4 for int8 over four seeds of every case).
+#: 2e-3 leaves a seven-fold margin; a rope position off by one or a
+#: cache mask one row too wide moves them by more (3e-3 to 0.09 here).
+STORED_TOL = dict(rtol=2e-3, atol=2e-3)
+
+#: (positions before the chunk, lengths) for B = 3 slots, block size 8,
+#: chunk width 8: ragged lengths beside an idle slot on a fresh cache; a
+#: second chunk of each prompt, crossing a block boundary; and one
+#: prefill slot beside two 1-token decode slots
+CHUNK_CASES = {
+    "ragged": ((0, 0, 0), (8, 3, 0)),
+    "second-chunk": ((8, 13, 6), (8, 5, 2)),
+    "mixed-decode": ((8, 13, 5), (1, 1, 8)),
+}
+
+
+def _touched_blocks(bt, pos, lens, bs):
+    return {int(bt[b, (pos[b] + c) // bs])
+            for b in range(len(pos)) for c in range(lens[b])}
+
+
+def _assert_pool_close(a, b, key, kv_dtype, msg):
+    """Committed rows agree: f32 pools to CHUNK_TOL; a bf16 pool may round
+    a value near a rounding boundary one bf16 step (at most 2**-7 of it)
+    the other way, an int8 pool one quantum; scales are f32."""
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    if key.endswith("_scale") or kv_dtype == "f32":
+        np.testing.assert_allclose(a, b, err_msg=msg, **CHUNK_TOL)
+    elif kv_dtype == "bf16":
+        np.testing.assert_allclose(a, b, rtol=2**-7, atol=1e-6, err_msg=msg)
+    else:
+        np.testing.assert_array_less(np.abs(a - b), 1.5, err_msg=msg)
+
+
+@pytest.mark.parametrize("case", sorted(CHUNK_CASES))
+@pytest.mark.parametrize("kv_dtype", transformer.KV_DTYPES)
+@pytest.mark.parametrize("arch", ["gpt2-124m", "qwen3-1.7b"])
+def test_prefill_chunk_paged_matches_scan(arch, kv_dtype, case):
+    cfg, params = _model(arch)
+    B, max_len, bs, C = 3, 64, 8, 8
+    pos, lens = (np.asarray(v, np.int32) for v in CHUNK_CASES[case])
+    rng = np.random.default_rng(21)
+    cache = transformer.init_paged_cache(cfg, B, max_len, bs, kv_dtype)
+    nb = max_len // bs
+    bt = np.arange(1, 1 + B * nb, dtype=np.int32).reshape(B, nb)
+    if pos.any():  # the prompts' history, committed by the scan
+        hist = rng.integers(0, cfg.vocab, (B, 16)).astype(np.int32)
+        _, cache = transformer.prefill_step_paged(
+            params, cfg, jnp.asarray(hist), cache, jnp.zeros((B,), jnp.int32),
+            jnp.asarray(bt), jnp.asarray(pos), block_size=bs,
+            kv_dtype=kv_dtype)
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab, (B, C)).astype(np.int32))
+    args = (params, cfg, tokens, cache, jnp.asarray(pos), jnp.asarray(bt),
+            jnp.asarray(lens))
+    kw = dict(block_size=bs, kv_dtype=kv_dtype)
+    logits_s, cache_s = transformer.prefill_step_paged(*args, **kw)
+    logits_c, cache_c = transformer.prefill_chunk_paged(*args, **kw)
+
+    assert logits_c.shape == logits_s.shape
+    assert logits_c.dtype == jnp.float32
+    tol = CHUNK_TOL if kv_dtype == "f32" else STORED_TOL
+    for b in range(B):
+        np.testing.assert_allclose(
+            np.asarray(logits_c)[b, :lens[b]],
+            np.asarray(logits_s)[b, :lens[b]],
+            err_msg=f"slot {b} logits", **tol)
+    touched = _touched_blocks(bt, pos, lens, bs)
+    untouched = [k for k in range(1, 1 + B * nb) if k not in touched]
+    assert jax.tree.structure(cache_c) == jax.tree.structure(cache_s)
+    for slot, leaves in cache_c["blocks"].items():
+        for key, leaf in leaves.items():
+            msg = f"{slot}/{key}"
+            _assert_pool_close(leaf[:, 1:], cache_s["blocks"][slot][key][:, 1:],
+                               key, kv_dtype, msg)
+            np.testing.assert_array_equal(
+                np.asarray(leaf)[:, untouched],
+                np.asarray(cache["blocks"][slot][key])[:, untouched],
+                err_msg=f"{msg}: a block outside the chunk changed")
+
+
+@pytest.mark.parametrize("arch", SERVE_ARCHS)
+def test_engine_picks_chunk_path_for_dense_attention(monkeypatch, arch):
+    """``_prefill_step_fn`` runs the one-pass chunk on dense-attention
+    configs and keeps the scan for MoE, MLA, SSM and hybrid ones, and
+    ``stats()['prefill_path']`` says which."""
+    from repro.serve import engine as engine_mod
+
+    expected = "chunk" if arch in ("gpt2-124m", "qwen3-1.7b") else "scan"
+    cfg, params = _model(arch)
+    called = []
+    for name, path in (("prefill_chunk_paged", "chunk"),
+                       ("prefill_step_paged", "scan")):
+        real = getattr(transformer, name)
+
+        def record(*a, _real=real, _path=path, **kw):
+            called.append(_path)
+            return _real(*a, **kw)
+
+        monkeypatch.setattr(transformer, name, record)
+    B, max_len, bs = 2, 32, 8
+    cache, bt = _fresh_paged(cfg, B, max_len, bs)
+    step = engine_mod._prefill_step_fn(cfg, bs, "f32")
+    logits, _ = step(params, jnp.zeros((B, 4), jnp.int32), cache,
+                     jnp.zeros((B,), jnp.int32), bt,
+                     jnp.asarray([4, 2], jnp.int32))
+    assert called == [expected]
+    assert logits.shape == (B, 4, cfg.vocab_padded)
+    eng = ServeEngine(cfg, params, max_batch=B, max_len=max_len,
+                      block_size=bs, prefill_chunk=4)
+    assert eng.stats()["prefill_path"] == expected
 
 
 # ---------------------------------------------------------------------------
